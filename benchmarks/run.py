@@ -48,6 +48,7 @@ def main() -> None:
     import benchmarks.router_sweep as router_sweep
     import benchmarks.swap_sweep as swap_sweep
     import benchmarks.zero_copy_sweep as zero_copy_sweep
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description="run all paper benchmarks")
     ap.add_argument("--smoke", action="store_true",
@@ -58,6 +59,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, metavar="SUBSTR",
                     help="run only benchmarks whose slug contains SUBSTR")
     args = ap.parse_args()
+    enable_compile_cache()
     os.makedirs(args.out_dir, exist_ok=True)
     rev = git_rev()
 

@@ -8,6 +8,9 @@ DistAttention micro-attention merge on a multi-device host mesh.
 
 import os
 
+# the DistAttention demo shards over a 2x4 mesh: eight virtual CPU devices
+# give it that on any host
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402
@@ -16,6 +19,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core.distkv import (GManager, RManager, dist_attention,  # noqa: E402
                                dist_attention_ref)
 from repro.core.paging import BlockAllocator  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.serving.simulator import make_workload, simulate_distkv  # noqa: E402
 
 
@@ -44,7 +48,7 @@ def debt_ledger_demo():
 
 def dist_attention_demo():
     print("\n== DistAttention: sequence-sharded micro-attention ==")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     b, h, hkv, dh, s = 4, 8, 2, 64, 512
     q = jax.random.normal(ks[0], (b, h, dh))

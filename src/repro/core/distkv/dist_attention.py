@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def micro_attention_partial(q, k, v, valid, *, scale: Optional[float] = None):
     """Shard-local Micro Attention (single-query view of
@@ -109,7 +107,7 @@ def dist_attention(mesh, q, k, v, context_lens, *, axis: str = "model"):
         o, m, l = micro_attention_partial(q_l, k_l, v_l, valid)
         return merge_partials(o, m, l, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None),
                   P()),

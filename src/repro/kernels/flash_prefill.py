@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -79,7 +77,7 @@ def flash_prefill(
     window: Optional[int] = None,
     q_block: int = 128,
     kv_block: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     b, s, h, dh = q.shape
     skv, hkv = k.shape[1], k.shape[2]
@@ -117,7 +115,7 @@ def flash_prefill(
             pltpu.VMEM((g, q_block, dh), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, s, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -110,7 +108,7 @@ def paged_attention(
     page_size: int,
     window: Optional[int] = None,
     return_partials: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Decode attention over a paged KV cache. Returns (B, H, Dh), or with
     ``return_partials`` the tuple ``(o_unnormalized?, m, l)`` — note ``o`` IS
@@ -158,7 +156,7 @@ def paged_attention(
             jax.ShapeDtypeStruct((b, hkv, g), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, context_lens, qg, k_pages, v_pages)

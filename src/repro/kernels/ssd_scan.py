@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams as _CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
                 state_ref, *, chunk: int, nc: int):
@@ -65,7 +63,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = False):
     """x: (b,l,h,p); dt: (b,l,h) fp32 post-softplus; A: (h,); B,C: (b,l,g,n).
     Returns (y (b,l,h,p) fp32, final_state (b,h,p,n) fp32)."""
     b, l, h, p = x.shape
@@ -105,7 +103,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xh, dth, A.astype(jnp.float32), bh, ch)

@@ -9,17 +9,25 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules place
+    arrays with ``NamedSharding``/``with_sharding_constraint`` and leave the
+    rest to the partitioner, so no jit needs an enclosing ``jax.set_mesh``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over whatever devices actually exist (tests/examples)."""
     n = len(jax.devices())
     mp = model_parallel if n % model_parallel == 0 else 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

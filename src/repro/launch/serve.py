@@ -21,6 +21,7 @@ import time
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.api import LLMService, SamplingParams
 
 
@@ -45,7 +46,24 @@ def build_netmodel(args):
     return NetworkModel(**kw)
 
 
-def build_instance(args):
+def build_engine(cfg, ecfg, *, seed: int = 0, device=None):
+    """A :class:`PagedEngine` serving ``cfg`` with random weights drawn
+    from ``seed``. The weights are made on ``device`` (default: the first
+    device) and committed there, and the engine keeps its KV pools beside
+    them, so N engines built for N devices are N independent replicas."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    from repro.models import Model
+    from repro.serving.engine import PagedEngine
+    device = device if device is not None else jax.devices()[0]
+    init = jax.jit(Model(cfg, remat=False).init,
+                   out_shardings=SingleDeviceSharding(device))
+    return PagedEngine(cfg, init(jax.random.PRNGKey(seed)), ecfg)
+
+
+def build_instance(args, index: int = 0):
+    """Instance ``index`` of the cluster; engine instances go round-robin
+    over the local devices, one replica per device."""
     telemetry = bool(args.trace or args.metrics_csv)
     if args.backend == "sim":
         from repro.serving.simulator import SimBackend
@@ -61,12 +79,10 @@ def build_instance(args):
                           cache_spill_pages=args.cache_spill_pages,
                           net=build_netmodel(args), trace=telemetry)
     import jax
-    from repro.models import Model
-    from repro.serving.engine import EngineConfig, PagedEngine
+    from repro.serving.engine import EngineConfig
     cfg = smoke_config(args.arch) if args.reduced else get_config(args.arch)
-    model = Model(cfg, remat=False)
-    params = model.init(jax.random.PRNGKey(0))
-    return PagedEngine(cfg, params, EngineConfig(
+    devices = jax.devices()
+    return build_engine(cfg, EngineConfig(
         num_pages=args.pages, page_size=args.page_size,
         max_slots=args.slots, use_kernel=args.use_kernel,
         enable_prefix_cache=args.prefix_cache,
@@ -74,7 +90,8 @@ def build_instance(args):
         host_pages=args.host_pages, swap_mode=args.swap_mode,
         victim_policy=args.victim_policy,
         speculative_swap=args.speculative_swap,
-        cache_spill_pages=args.cache_spill_pages))
+        cache_spill_pages=args.cache_spill_pages),
+        device=devices[index % len(devices)])
 
 
 def parse_roles_arg(args):
@@ -110,7 +127,7 @@ def build_backend(args):
     if args.instances <= 1:
         return build_instance(args)
     from repro.serving.router import RouterBackend
-    children = [build_instance(args) for _ in range(args.instances)]
+    children = [build_instance(args, i) for i in range(args.instances)]
     try:
         return RouterBackend(children, policy=args.policy,
                              prefix_share=args.prefix_share,
@@ -142,7 +159,8 @@ def main():
     ap.add_argument("--best-of", type=int, default=1,
                     help="n parallel samples per prompt (COW-forked KV)")
     ap.add_argument("--use-kernel", action="store_true",
-                    help="Pallas paged-attention (interpret mode on CPU)")
+                    help="Pallas paged-attention kernel for decode (Mosaic on "
+                         "a TPU, interpret mode on the CPU)")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="radix-tree prefix KV cache (cross-request reuse)")
     from repro.core.scheduling import CHUNK_POLICIES
@@ -239,6 +257,7 @@ def main():
                          "CSV after the run")
     args = ap.parse_args()
 
+    enable_compile_cache()
     backend = build_backend(args)
     svc = LLMService(backend)
     instance = backend.children[0] if hasattr(backend, "children") \

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs import ArchConfig, InputShape
 from repro.launch.mesh import data_axes
 from repro.models.layers import ShardingPolicy
@@ -122,7 +121,7 @@ class MeshPolicy(ShardingPolicy):
             aux = e * jnp.sum(frac_tok * jnp.mean(probs, axis=0))
             return y, aux[None]
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(dpa, None), P(), P("model", dpa, None),
                       P("model", dpa, None), P("model", dpa, None)),

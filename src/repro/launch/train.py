@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config, smoke_config
 from repro.launch import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import Model
 from repro.training import checkpoint, optimizer
@@ -41,6 +42,7 @@ def main():
                     help="use the 16x16 pod mesh (requires 256 devices)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.reduced else get_config(args.arch)
     mesh = (make_production_mesh() if args.production_mesh
             else make_host_mesh(args.model_parallel))

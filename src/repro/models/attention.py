@@ -308,8 +308,24 @@ def gqa_decode(cfg, p, x, cache: KVCache, pos, *, window=None,
 
     k = policy.act(k, "kvcache_bskd")
     v = policy.act(v, "kvcache_bskd")
-    g = h // hkv
-    qg = q.reshape(b, hkv, g, dh)
+    out = decode_attention(q[:, 0], k, v, mask)
+    out = out.reshape(b, 1, h * dh).astype(x.dtype)
+    y = dense(p["wo"], out, policy, "act_bsd")
+    return y, cache
+
+
+def decode_attention(q, k, v, mask):
+    """One query token per sequence against its keys, with the numerics of
+    every attention in the models: bf16 operands into both matmuls, fp32
+    accumulation and fp32 softmax. The serving engine's decode steps call
+    this too, so a token decoded by the engine matches the model's own
+    decode.
+
+    q: (B, H, Dh); k, v: (B, S, Hkv, Dh); mask: (B, S) bool. Returns
+    (B, H, Dh) fp32."""
+    b, h, dh = q.shape
+    hkv = k.shape[2]
+    qg = q.reshape(b, hkv, h // hkv, dh)
     scores = jnp.einsum("bhgd,bshd->bhgs", qg.astype(jnp.bfloat16),
                         k.astype(jnp.bfloat16),
                         preferred_element_type=jnp.float32)
@@ -319,9 +335,24 @@ def gqa_decode(cfg, p, x, cache: KVCache, pos, *, window=None,
     out = jnp.einsum("bhgs,bshd->bhgd", probs.astype(jnp.bfloat16),
                      v.astype(jnp.bfloat16),
                      preferred_element_type=jnp.float32)
-    out = out.reshape(b, 1, h * dh).astype(x.dtype)
-    y = dense(p["wo"], out, policy, "act_bsd")
-    return y, cache
+    return out.reshape(b, h, dh)
+
+
+def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens,
+                           *, page_size: int, window: Optional[int] = None):
+    """:func:`decode_attention` over a paged KV cache: gathers each
+    sequence's pages through its block table. Same signature as the Pallas
+    ``paged_attention`` kernel. q: (B, H, Dh); pages: (P, ps, Hkv, Dh);
+    block_tables: (B, n); context_lens: (B,)."""
+    b = q.shape[0]
+    _, _, hkv, dh = k_pages.shape
+    k = k_pages[block_tables].reshape(b, -1, hkv, dh)
+    v = v_pages[block_tables].reshape(b, -1, hkv, dh)
+    pos = jnp.arange(block_tables.shape[1] * page_size)
+    mask = pos[None, :] < context_lens[:, None]
+    if window is not None:
+        mask &= pos[None, :] > context_lens[:, None] - 1 - window
+    return decode_attention(q, k, v, mask).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -20,8 +20,8 @@ Execution model per iteration (continuous batching):
    boundary;
 3. all running sequences advance one token in a single batched decode step
    over fixed slots — attention reads scattered pages via the block table
-   (``repro.kernels.paged_attention``; a pure-XLA reference path is the
-   default on CPU, the Pallas kernel is switchable via ``use_kernel``), and
+   (by default the models' own ``paged_decode_attention`` in pure XLA; the
+   Pallas ``repro.kernels.paged_attention`` kernel via ``use_kernel``), and
    sampling runs **fused with vectorized per-slot parameters**: each slot
    applies its own request's temperature / top-k / top-p / seed
    (``repro.models.sampling.sample_batch``), and stop/eos/length finish
@@ -71,14 +71,15 @@ from repro.core.prefixcache.radix import PrefixCache
 from repro.core.scheduling.iteration import IterationScheduler
 from repro.core.scheduling.request import Phase, Request
 from repro.core.telemetry import MetricsRegistry, Tracer
-from repro.kernels import ops, ref
+from repro.kernels import ops
 from repro.models import Model
 from repro.models import moe as moe_mod
 from repro.models import sampling
 from repro.models.layers import embed, rms_norm, unembed
 from repro.models.attention import (_mla_scale, blockwise_attention,
-                                    gqa_layer, mla_effective_ctx,
-                                    mla_effective_kv, mla_layer)
+                                    decode_attention, gqa_layer,
+                                    mla_effective_ctx, mla_effective_kv,
+                                    mla_layer, paged_decode_attention)
 from repro.serving.api import SamplingParams
 
 
@@ -175,8 +176,12 @@ class PagedEngine:
         # ckv / krope); all page-granular plumbing (COW, swap, spill,
         # export) indexes only axis 1 and never the trailing token shape.
         shape_a, shape_b = self.kv_layout.pool_shapes(P + 1, ps)
-        self.k_pages = jnp.zeros(shape_a, cfg.param_dtype)
-        self.v_pages = jnp.zeros(shape_b, cfg.param_dtype)
+        # the pools live where the params were committed: one engine per
+        # device, each replica on its own chip (one device only — the
+        # unpacking refuses params spread over several)
+        (self.device,) = jax.tree.leaves(params)[0].devices()
+        self.k_pages = jnp.zeros(shape_a, cfg.param_dtype, device=self.device)
+        self.v_pages = jnp.zeros(shape_b, cfg.param_dtype, device=self.device)
         self.allocator = BlockAllocator(P, ps,
                                         host_blocks=ecfg.host_pages,
                                         layout=self.kv_layout)
@@ -464,7 +469,8 @@ class PagedEngine:
         tokens: (n,), positions: (n,), block_tables: (n, max_pages),
         ctx_lens: (n,) (0 = inactive slot). Returns (logits (n, V), pages).
 
-        GQA runs the Pallas/reference paged-attention kernel; MLA gathers
+        GQA runs the models' paged decode attention or, with
+        ``use_kernel``, the Pallas paged-attention kernel; MLA gathers
         the latent pools and attends in the matrix-absorbed effective
         single-kv-head form (the Pallas kernel is GQA-shaped)."""
         cfg = self.cfg
@@ -518,7 +524,7 @@ class PagedEngine:
                     vp2 = vp.at[page_slot, in_page].set(
                         v[:, 0].astype(vp.dtype))
                     att_fn = ops.paged_attention if ecfg.use_kernel \
-                        else ref.paged_attention_ref
+                        else paged_decode_attention
                     att = att_fn(q[:, 0], kp2, vp2, block_tables, ctx_lens,
                                  page_size=ps, window=window)
                     return att.reshape(n, 1, cfg.num_heads, cfg.head_dim), \
@@ -547,11 +553,12 @@ class PagedEngine:
         reduce to the plain paged path numerically); rk, rv:
         (L, n, R, *token_shape) the borrowed pages' payloads gathered from
         each creditor's pools (K/V for GQA, ckv/krope for MLA), covering
-        absolute positions ``[0, r_base[i])`` of slot ``i``. Per layer, the
-        local paged partial and the remote partial are combined with the
-        DistAttention log-sum-exp merge — exactly the InfiniteLLM
-        micro-attention aggregation, with the borrower reading the
-        creditor's pages in place of an RDMA fetch.
+        absolute positions ``[0, r_base[i])`` of slot ``i``, read from the
+        creditor's pages in place of an RDMA fetch. GQA attends over the
+        borrowed and the local keys in one softmax, with the numerics of
+        the model's own decode (:func:`decode_attention`); MLA merges the
+        local and remote partials with the DistAttention log-sum-exp merge
+        (InfiniteLLM's micro-attention aggregation).
         """
         cfg = self.cfg
         ecfg = self.ecfg
@@ -613,16 +620,17 @@ class PagedEngine:
                         n, -1, cfg.num_kv_heads, cfg.head_dim)
                     vall = vp2[block_tables].reshape(
                         n, -1, cfg.num_kv_heads, cfg.head_dim)
-                    s_loc = kall.shape[1]
-                    mask_l = (jnp.arange(s_loc)[None, :] <
-                              loc_lens[:, None])[:, None, :]  # (n, 1, S_loc)
-                    o_l, m_l, l_l = attention_partial(q, kall, vall, mask_l)
-                    mask_r = (jnp.arange(n_remote)[None, :] <
-                              r_base[:, None])[:, None, :]
-                    o_r, m_r, l_r = attention_partial(q, rk_i, rv_i, mask_r)
-                    att = merge_partials_tree([o_l, o_r], [m_l, m_r],
-                                              [l_l, l_r])  # (n, 1, H, Dh)
-                    return att.astype(q.dtype), (kp2, vp2)
+                    mask_l = jnp.arange(kall.shape[1])[None, :] < \
+                        loc_lens[:, None]  # (n, S_loc)
+                    mask_r = jnp.arange(n_remote)[None, :] < r_base[:, None]
+                    # the borrowed keys hold positions [0, r_base), ahead of
+                    # every local key: one softmax over both, in the
+                    # model's own decode numerics
+                    att = decode_attention(
+                        q[:, 0], jnp.concatenate([rk_i, kall], 1),
+                        jnp.concatenate([rv_i, vall], 1),
+                        jnp.concatenate([mask_r, mask_l], 1))
+                    return att.reshape(q.shape).astype(q.dtype), (kp2, vp2)
 
                 y, (kp2, vp2) = gqa_layer(cfg, p_i, xx, positions[:, None],
                                           attend, mlp_fn=self._mlp_fn(seg))
@@ -706,8 +714,11 @@ class PagedEngine:
             idx = jnp.asarray(lease.blocks, jnp.int32)
             L = self.nlayers
             pa, pb = self.kv_layout.pools
-            hit = (hk[:, idx].reshape((L, -1) + pa.token_shape),
-                   hv[:, idx].reshape((L, -1) + pb.token_shape))
+            # the creditor's pools may sit on another device: the gathered
+            # pages move to this engine's device once per lease
+            hit = jax.device_put(
+                (hk[:, idx].reshape((L, -1) + pa.token_shape),
+                 hv[:, idx].reshape((L, -1) + pb.token_shape)), self.device)
             self._lease_kv_cache[key] = hit
         return hit
 
@@ -1089,10 +1100,13 @@ class PagedEngine:
             check_schema(self.kv_layout.schema, p[0],
                          where="page-payload import")
         idx = jnp.asarray(list(blocks), jnp.int32)
-        k = jnp.stack([jnp.asarray(p[1], self.k_pages.dtype)
-                       for p in payloads], axis=1)  # (L, n, ps, *token_shape)
-        v = jnp.stack([jnp.asarray(p[2], self.v_pages.dtype)
-                       for p in payloads], axis=1)
+        # host payloads (exported by a peer on any device) go straight to
+        # this engine's device
+        k, v = jax.device_put(
+            (np.stack([p[1] for p in payloads], axis=1).astype(
+                self.k_pages.dtype),  # (L, n, ps, *token_shape)
+             np.stack([p[2] for p in payloads], axis=1).astype(
+                 self.v_pages.dtype)), self.device)
         self.k_pages = self.k_pages.at[:, idx].set(k)
         self.v_pages = self.v_pages.at[:, idx].set(v)
 

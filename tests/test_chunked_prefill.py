@@ -490,8 +490,15 @@ def test_engine_chunked_equals_monolithic(model_setup_f32):
     # ...and the same prompt KV state, page layout aside
     km, vm = _gathered_prompt_kv(mono_eng, 0, len(prompt))
     kc, vc = _gathered_prompt_kv(chunk_eng, 0, len(prompt))
-    np.testing.assert_allclose(kc, km, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(vc, vm, rtol=1e-5, atol=1e-6)
+    # Bound for a float32 model: the two prefills sum attention over
+    # different padded key lengths, so their f32 sums differ in the last
+    # bits (4.3e-6 measured with f32 attention operands). The models'
+    # attention rounds q, k and the softmax weights to bf16 (2^-8 steps),
+    # and such a last-bit difference flips some of those roundings, which
+    # the next layer's K/V inherit: 6.4e-5 measured. A wrong position, page
+    # or mask moves K/V by O(0.1).
+    np.testing.assert_allclose(kc, km, rtol=1e-5, atol=5e-4)
+    np.testing.assert_allclose(vc, vm, rtol=1e-5, atol=5e-4)
 
     # the remaining decode is token-identical too
     mono_eng.run_to_completion()

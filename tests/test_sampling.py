@@ -132,6 +132,6 @@ def test_temperature_mass_property(seed):
     logits = jnp.asarray(rng.normal(size=(1, 32)).astype(np.float32) * 3)
     lo = [int(_sample(logits, [s], [0], [0.3])[0][0]) for s in range(40)]
     hi = [int(_sample(logits, [s], [0], [3.0])[0][0]) for s in range(40)]
-    p = np.asarray(jnp.exp(logits[0] - jnp.max(logits[0])))
+    p = np.array(jnp.exp(logits[0] - jnp.max(logits[0])))  # writable copy
     p /= p.sum()
     assert np.mean(p[lo]) >= np.mean(p[hi]) - 1e-3
