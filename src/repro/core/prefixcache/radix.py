@@ -93,6 +93,7 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core.paging.allocator import BlockAllocator, OutOfBlocks
+from repro.core.telemetry import NO_SPAN
 
 
 @dataclasses.dataclass
@@ -170,6 +171,9 @@ class PrefixCache:
         self.peer_drop_fn = None
         self.peer_spilled_pages = 0
         self.peer_restored_pages = 0
+        # telemetry: a repro.core.telemetry.Tracer wired by the execution
+        # backend (prefix.match / insert / evict spans), or None
+        self.trace = None
 
     # -- lookup -----------------------------------------------------------------
     def match(self, tokens: Sequence[int], *,
@@ -186,6 +190,12 @@ class PrefixCache:
         ``prompt_len - 1`` so a fully-cached prompt still prefills its last
         token for logits). ``probe=True`` is fully side-effect-free for
         routing policies probing every instance."""
+        tr = self.trace
+        with tr.span("prefix", "match") if tr is not None else NO_SPAN:
+            return self._match(tokens, max_tokens, probe)
+
+    def _match(self, tokens: Sequence[int], max_tokens: Optional[int],
+               probe: bool) -> List[RadixNode]:
         ps = self.page_size
         limit = len(tokens) if max_tokens is None else \
             min(max_tokens, len(tokens))
@@ -246,6 +256,13 @@ class PrefixCache:
         intact. Disabled with ``token_level=False``."""
         if not self.token_level:
             return None
+        tr = self.trace
+        with tr.span("prefix", "match") if tr is not None else NO_SPAN:
+            return self._match_partial(tokens, path, max_tokens, probe)
+
+    def _match_partial(self, tokens: Sequence[int], path: List[RadixNode],
+                       max_tokens: Optional[int], probe: bool
+                       ) -> Optional[Tuple[RadixNode, int]]:
         ps = self.page_size
         limit = len(tokens) if max_tokens is None else \
             min(max_tokens, len(tokens))
@@ -292,6 +309,11 @@ class PrefixCache:
         """Insert the full pages of ``tokens`` (page ``i`` backed by
         ``blocks[i]``). Pages already cached are skipped; new pages are
         adopted with an extra allocator reference. Returns #pages adopted."""
+        tr = self.trace
+        with tr.span("prefix", "insert") if tr is not None else NO_SPAN:
+            return self._insert(tokens, blocks)
+
+    def _insert(self, tokens: Sequence[int], blocks: Sequence[int]) -> int:
         ps = self.page_size
         node, new = self.root, 0
         self._clock += 1
@@ -407,6 +429,11 @@ class PrefixCache:
         cache without reclaiming any memory. With a spill budget, a
         candidate's KV moves to a host page instead of dying (the device
         page is freed either way). Returns blocks actually freed."""
+        tr = self.trace
+        with tr.span("prefix", "evict") if tr is not None else NO_SPAN:
+            return self._evict(n_blocks, spill)
+
+    def _evict(self, n_blocks: int, spill: bool) -> int:
         freed = 0
         progress = True
         # one tree walk per pass, not per freed block; extra passes only when

@@ -87,6 +87,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from repro.core.paging.allocator import BlockAllocator, BlockTable
 from repro.core.prefixcache.radix import PrefixCache
 from repro.core.scheduling.request import Phase, Request
+from repro.core.telemetry import NO_SPAN
 
 CHUNK_POLICIES = ("decode_first", "prefill_first", "monolithic", "solo")
 # what happens to a preemption victim's KV:
@@ -409,6 +410,12 @@ class IterationScheduler:
 
     # -- one iteration ------------------------------------------------------------
     def schedule(self) -> IterationPlan:
+        """Plan one iteration: decodes, prefill chunks, admissions."""
+        tr = self.trace
+        with tr.span("sched", "schedule") if tr is not None else NO_SPAN:
+            return self._schedule()
+
+    def _schedule(self) -> IterationPlan:
         plan = IterationPlan(prefill=[], decode=[], preempted=[], cow=[],
                              chunks=[])
         self._budget = self.max_tokens
@@ -935,6 +942,12 @@ class IterationScheduler:
 
     def complete_iteration(self, plan: IterationPlan, now: float) -> List[Request]:
         """Mark phases + retire finished requests. Returns finished list."""
+        tr = self.trace
+        with tr.span("sched", "complete") if tr is not None else NO_SPAN:
+            return self._complete_iteration(plan, now)
+
+    def _complete_iteration(self, plan: IterationPlan,
+                            now: float) -> List[Request]:
         finished = []
         tr = self.trace
         for req in plan.prefill:

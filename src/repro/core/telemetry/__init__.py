@@ -1,8 +1,8 @@
 """Unified telemetry: structured event tracing, per-iteration metric
 timelines, and Chrome/Perfetto trace export for the serving stack."""
 
-from .tracer import Event, Tracer, merge_events
-from .metrics import Histogram, MetricsRegistry, percentile
+from .tracer import NO_SPAN, Event, Tracer, merge_events
+from .metrics import MetricsRegistry, percentile
 from .export import (
     export_chrome_trace,
     export_metrics_csv,
@@ -14,9 +14,9 @@ from .export import (
 
 __all__ = [
     "Event",
+    "NO_SPAN",
     "Tracer",
     "merge_events",
-    "Histogram",
     "MetricsRegistry",
     "percentile",
     "export_chrome_trace",

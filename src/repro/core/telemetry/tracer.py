@@ -23,6 +23,17 @@ Design constraints:
   perfectly reproducible — no wall time anywhere), a wall-clock engine
   updates the ``now`` attribute at each ``step``. A cluster router merges
   per-child tracers onto one timeline by sorting on these timestamps.
+* **Spans on the profiler's clock.** :meth:`Tracer.span` times a phase of
+  the program's own work (``<cat>.<name>``, e.g. ``engine.iteration``,
+  ``sched.schedule``, ``prefix.match``). It opens a
+  ``jax.profiler.TraceAnnotation`` so the phase lands on the host line of
+  the running thread in a profiler trace, on the same clock as the device
+  planes; on a wall-clock tracer it also pushes a ring ``complete`` event.
+  A disabled site enters the shared :data:`NO_SPAN` instead::
+
+      tr = self.trace
+      with tr.span("sched", "schedule") if tr is not None else NO_SPAN:
+          ...
 
 Event vocabulary (``cat``/``name``; ``args`` carry cause attribution):
 
@@ -62,13 +73,29 @@ Event vocabulary (``cat``/``name``; ``args`` carry cause attribution):
                       (``src``/``dst``/``mode``/``pages``) and ended at
                       the decode host's once the transfer is charged
 ``engine``            per-iteration ``iteration`` complete events (one
-                      track per instance), engine ``chunk`` executions
+                      track per instance); ``chunk``: the *dispatch* of one
+                      prefill chunk (``start``/``length``/``last``), not
+                      its device time; ``sync``: the host waiting for the
+                      device (a blocking device->host read)
+``service``           ``poll``: one ``LLMService.poll`` (arrival injection,
+                      the backend step, chunk building)
+``sched`` (spans)     ``schedule`` / ``complete``: planning and completing
+                      one iteration
+``prefix``            ``match`` / ``insert`` / ``evict``: radix-tree
+                      lookup, insertion and eviction
 ====================  =====================================================
+
+Span names are ``<cat>.<name>`` and are never renamed: the benchmark's
+per-layer metrics find them by name in the profiler trace.
 """
 
 from __future__ import annotations
 
+import contextlib
+import time
 from typing import Callable, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Chrome trace-event phases used here: X=complete, i=instant,
 # b/e=async span begin/end, C=counter, M=metadata (added by the exporter)
@@ -77,6 +104,10 @@ PH_INSTANT = "i"
 PH_BEGIN = "b"
 PH_END = "e"
 PH_COUNTER = "C"
+
+# entered by an emission site whose owner's ``trace`` is None: one shared
+# object, so the disabled path builds no span, dict or string
+NO_SPAN = contextlib.nullcontext()
 
 
 class Event:
@@ -116,7 +147,9 @@ class Tracer:
     ``None`` means the owner updates :attr:`now` explicitly (wall-clock
     engines set it to the caller-supplied ``now`` each ``step``).
     ``iteration`` is likewise owner-updated per step so every event carries
-    the iteration it belongs to.
+    the iteration it belongs to, and ``t_step0`` is the monotonic time at
+    which ``now`` was set: a span's ring event starts at
+    ``now + (t_enter - t_step0)``.
     """
 
     def __init__(self, capacity: int = 131_072, *,
@@ -128,6 +161,7 @@ class Tracer:
         self.clock = clock
         self.instance = instance
         self.now = 0.0
+        self.t_step0 = time.monotonic() if clock is None else 0.0
         self.iteration = 0
         self._buf: List[Event] = []
         self._head = 0  # next overwrite slot once the ring is full
@@ -180,6 +214,16 @@ class Tracer:
         self._push(Event(self._ts(ts), "metrics", name, PH_COUNTER,
                          self.instance, None, self.iteration, None, values))
 
+    def span(self, cat: str, name: str, *, rid: Optional[int] = None,
+             **args) -> "_Span":
+        """Context manager timing one phase of the program's work; yields
+        its ``args`` dict, to which the site may add values it only knows
+        at exit. Opens ``TraceAnnotation("<cat>.<name>")``; on a wall-clock
+        tracer (``clock is None``) it also pushes a ``complete`` event
+        whose ``dur`` is the measured monotonic duration. A virtual-clock
+        tracer pushes nothing, so sim traces stay bit-reproducible."""
+        return _Span(self, cat, name, rid, args)
+
     # -- access -----------------------------------------------------------------
 
     def __len__(self) -> int:
@@ -191,6 +235,36 @@ class Tracer:
 
     def __iter__(self) -> Iterator[Event]:
         return iter(self.events())
+
+
+class _Span:
+    """One :meth:`Tracer.span`; the ring event is pushed at exit."""
+
+    __slots__ = ("tracer", "cat", "name", "rid", "args", "ann", "t0")
+
+    def __init__(self, tracer: Tracer, cat: str, name: str,
+                 rid: Optional[int], args: dict):
+        self.tracer = tracer
+        self.cat = cat
+        self.name = name
+        self.rid = rid
+        self.args = args
+
+    def __enter__(self) -> dict:
+        self.ann = TraceAnnotation(f"{self.cat}.{self.name}")
+        self.ann.__enter__()
+        if self.tracer.clock is None:
+            self.t0 = time.monotonic()
+        return self.args
+
+    def __exit__(self, *exc) -> None:
+        tr = self.tracer
+        if tr.clock is None:
+            dur = time.monotonic() - self.t0
+            tr._push(Event(tr.now + (self.t0 - tr.t_step0), self.cat,
+                           self.name, PH_COMPLETE, tr.instance, self.rid,
+                           tr.iteration, dur, self.args or None))
+        self.ann.__exit__(*exc)
 
 
 def merge_events(tracers) -> List[Event]:

@@ -34,7 +34,7 @@ from typing import (Dict, Iterable, Iterator, List, Optional, Protocol,
                     Sequence, Tuple, runtime_checkable)
 
 from repro.core.scheduling.request import Request
-from repro.core.telemetry import percentile
+from repro.core.telemetry import NO_SPAN, percentile
 
 # finish reasons (Request.finish_reason / RequestOutput.finish_reason)
 FINISH_STOP = "stop"                  # hit one of SamplingParams.stop_token_ids
@@ -314,6 +314,23 @@ class LLMService:
         backends; virtual-clock backends keep their own time and are
         fast-forwarded across idle gaps. ``collect=False`` skips building
         per-token chunks (drain/replay: nobody consumes them)."""
+        tr = getattr(self.backend, "trace", None)
+        with tr.span("service", "poll") if tr is not None else NO_SPAN:
+            chunks, t = self._poll_once(now, collect)
+        if not self._progressed and self._queue and not self.backend.has_work:
+            if self.backend.clock() is not None:
+                # virtual clock idle before the next arrival: jump ahead
+                self.backend.advance_to(self._queue[0].arrival_time)
+                return self.poll(now, collect=collect)
+            if now is None:
+                # wall clock, service-owned time: sleep out the gap
+                time.sleep(max(0.0, self._queue[0].arrival_time - t))
+                return self.poll(None, collect=collect)
+        return chunks
+
+    def _poll_once(self, now: Optional[float], collect: bool
+                   ) -> Tuple[List[CompletionChunk], float]:
+        """One poll's work: returns its chunks and the time it ran at."""
         t = now if now is not None else self.backend.clock()
         if t is None:
             # wall-clock backend, no caller time: measure from first poll so
@@ -359,16 +376,7 @@ class LLMService:
             getattr(self.backend, "iterations", None) != iters_before
         self._progressed = bool(chunks) or bool(finished) or injected \
             or stepped
-        if not self._progressed and self._queue and not self.backend.has_work:
-            if self.backend.clock() is not None:
-                # virtual clock idle before the next arrival: jump ahead
-                self.backend.advance_to(self._queue[0].arrival_time)
-                return self.poll(now, collect=collect)
-            if now is None:
-                # wall clock, service-owned time: sleep out the gap
-                time.sleep(max(0.0, self._queue[0].arrival_time - t))
-                return self.poll(None, collect=collect)
-        return list(chunks.values())
+        return list(chunks.values()), t
 
     def drain(self, max_iters: int = 1_000_000) -> None:
         """Poll until every submitted request finished or the backend can

@@ -70,7 +70,7 @@ from repro.core.paging.layout import KVPageLayout, check_schema
 from repro.core.prefixcache.radix import PrefixCache
 from repro.core.scheduling.iteration import IterationScheduler
 from repro.core.scheduling.request import Phase, Request
-from repro.core.telemetry import MetricsRegistry, Tracer
+from repro.core.telemetry import NO_SPAN, MetricsRegistry, Tracer
 from repro.kernels import ops
 from repro.models import Model
 from repro.models import moe as moe_mod
@@ -248,12 +248,14 @@ class PagedEngine:
         # wall-clock engine cannot advance time, so observability only
         self.net_time = 0.0
         # telemetry: events are stamped off the caller-supplied `now` (the
-        # tracer's mutable .now, updated each step) with jitted-call
-        # durations measured on the monotonic clock
+        # tracer's mutable .now, updated each step) plus the monotonic time
+        # elapsed since the step began; spans also land in a profiler trace
         if ecfg.enable_telemetry:
             self.trace = Tracer()
             self.metrics = MetricsRegistry()
             self.scheduler.trace = self.trace
+            if self.prefix_cache is not None:
+                self.prefix_cache.trace = self.trace
         else:
             self.trace = None
             self.metrics = None
@@ -795,7 +797,9 @@ class PagedEngine:
         toks, lps = self._sample_fn(logits, jnp.asarray(seeds),
                                     jnp.asarray(steps), jnp.asarray(temp),
                                     jnp.asarray(topk), jnp.asarray(topp))
-        return np.asarray(toks), np.asarray(lps)
+        tr = self.trace
+        with tr.span("engine", "sync") if tr is not None else NO_SPAN:
+            return np.asarray(toks), np.asarray(lps)
 
     def _sample_one(self, req: Request, logits_row):
         toks, lps = self._sample_rows(logits_row[None], [req])
@@ -812,16 +816,25 @@ class PagedEngine:
     # -- engine loop ------------------------------------------------------------
 
     def step(self, now: Optional[float] = None) -> List[Request]:
-        """Run ONE iteration (ORCA's unit of scheduling)."""
-        now = time.monotonic() if now is None else now
+        """Run ONE iteration (ORCA's unit of scheduling). ``now`` is the
+        caller's clock at the step's start; a token is stamped when it
+        reaches the host, ``now`` plus the monotonic time elapsed since."""
+        t0 = time.monotonic()
+        now = t0 if now is None else now
         tr = self.trace
-        t_wall0 = 0.0
         if tr is not None:
-            # scheduler events default to `now`; sub-iteration slices
-            # (chunk executions) are offset by elapsed monotonic time
+            # scheduler instants default to `now`; spans are offset by the
+            # monotonic time elapsed since t0
             tr.now = now
+            tr.t_step0 = t0
             tr.iteration = self.iterations
-            t_wall0 = time.monotonic()
+        with tr.span("engine", "iteration") if tr is not None else NO_SPAN \
+                as args:
+            return self._step(now, t0, args)
+
+    def _step(self, now: float, t0: float,
+              args: Optional[dict]) -> List[Request]:
+        tr = self.trace
         plan = self.scheduler.schedule()
         if self._lease_kv_cache:  # drop gathers of released leases
             self._prune_lease_cache()
@@ -846,6 +859,8 @@ class PagedEngine:
             if req.output:
                 self.last_token[slot] = req.output[-1]
         if plan.empty:
+            if args is not None:
+                args.update(tokens=0, decodes=0, chunks=0)
             # a self-preempted request can leave an otherwise-empty plan:
             # run completion anyway so the max_preemptions drop policy
             # applies (otherwise it bounces in waiting forever)
@@ -862,6 +877,7 @@ class PagedEngine:
 
         # --- prefill chunks (initiation phase) ---
         forked: List[Request] = []
+        t_host = now  # when the newest sampled tokens reached the host
         ps = self.ecfg.page_size
         for ch in plan.chunks:
             req = ch.req
@@ -893,22 +909,21 @@ class PagedEngine:
                     self.scheduler.leases[req.request_id])
             else:
                 rk, rv = self._no_remote(self.k_pages.dtype)
-            t_chunk0 = time.monotonic() if tr is not None else 0.0
-            logits, self.k_pages, self.v_pages = self._prefill_chunk_fn(
-                self.params, self.k_pages, self.v_pages,
-                jnp.asarray(tok_arr)[None], jnp.asarray(page_arr),
-                jnp.int32(ch.start), jnp.int32(ch.length), jnp.int32(r_base),
-                rk, rv)
-            if tr is not None:
-                tr.complete("engine", "chunk", rid=req.request_id,
-                            ts=now + (t_chunk0 - t_wall0),
-                            dur=time.monotonic() - t_chunk0,
-                            start=ch.start, length=ch.length,
-                            last=ch.is_last)
+            # the span times the dispatch (the call returns before the
+            # device has run the chunk)
+            with tr.span("engine", "chunk", rid=req.request_id,
+                         start=ch.start, length=ch.length, last=ch.is_last) \
+                    if tr is not None else NO_SPAN:
+                logits, self.k_pages, self.v_pages = self._prefill_chunk_fn(
+                    self.params, self.k_pages, self.v_pages,
+                    jnp.asarray(tok_arr)[None], jnp.asarray(page_arr),
+                    jnp.int32(ch.start), jnp.int32(ch.length),
+                    jnp.int32(r_base), rk, rv)
             if ch.is_last:
                 tok, lp = self._sample_one(req, logits)
-                self._emit(req, slot, tok, lp, now)
-                forked.extend(self._fork_children(req, logits, now))
+                t_host = now + (time.monotonic() - t0)
+                self._emit(req, slot, tok, lp, t_host)
+                forked.extend(self._fork_children(req, logits, t_host))
 
         # best-of-n children join the plan so completion/insertion sees them
         plan.prefill.extend(forked)
@@ -947,20 +962,19 @@ class PagedEngine:
                     jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
                     jnp.asarray(lens))
             sampled, lps = self._sample_rows(logits, row_reqs)
+            t_host = now + (time.monotonic() - t0)
             for req in decode_reqs:
                 slot = self.slots[req.request_id]
                 self._emit(req, slot, int(sampled[slot]), float(lps[slot]),
-                           now)
+                           t_host)
 
-        finished = self.scheduler.complete_iteration(plan, now)
+        finished = self.scheduler.complete_iteration(plan, t_host)
         for req in finished:
             if req.request_id in self.slots:
                 self.free_slots.append(self.slots.pop(req.request_id))
-        if tr is not None:
-            dur = time.monotonic() - t_wall0
-            tr.complete("engine", "iteration", ts=now, dur=dur,
-                        tokens=plan.token_count(),
-                        decodes=len(plan.decode), chunks=len(plan.chunks))
+        if args is not None:
+            args.update(tokens=plan.token_count(), decodes=len(plan.decode),
+                        chunks=len(plan.chunks))
             m = self.metrics
             m.gauge("kv_util_frac",
                     self.allocator.num_used / self.allocator.num_blocks)
@@ -976,7 +990,10 @@ class PagedEngine:
                 m.gauge("swap_pending_pages",
                         self.allocator.pending_out_pages)
             if self.prefix_cache is not None:
-                m.gauge("prefix_hit_rate", self.prefix_cache.hit_rate)
+                # cumulative: a reader takes the window's differences
+                m.counters["prefix_hit_tokens"] = self.prefix_cache.hit_tokens
+                m.counters["prefix_lookup_tokens"] = \
+                    self.prefix_cache.lookup_tokens
             m.count("tokens", plan.token_count())
             m.count("decode_tokens", len(plan.decode))
             m.count("prefill_tokens", sum(c.length for c in plan.chunks))
@@ -985,7 +1002,6 @@ class PagedEngine:
             m.count("swap_ins", len(plan.swap_in))
             m.count("swap_issues", len(plan.swap_issue))
             m.count("swap_cancels", len(plan.swap_cancel))
-            m.observe("iteration_time_s", dur)
             m.snapshot(now, self.iterations)
         self.iterations += 1
         return finished
@@ -1023,8 +1039,10 @@ class PagedEngine:
         hook, called before the freed device pages can be reallocated)."""
         devs = jnp.asarray([d for d, _ in pairs], jnp.int32)
         hosts = [h for _, h in pairs]
-        self.h_k_pages[:, hosts] = np.asarray(self.k_pages[:, devs])
-        self.h_v_pages[:, hosts] = np.asarray(self.v_pages[:, devs])
+        tr = self.trace
+        with tr.span("engine", "sync") if tr is not None else NO_SPAN:
+            self.h_k_pages[:, hosts] = np.asarray(self.k_pages[:, devs])
+            self.h_v_pages[:, hosts] = np.asarray(self.v_pages[:, devs])
         self.swapped_out += 1
 
     def _swap_out_issue(self, pairs) -> None:
@@ -1062,8 +1080,10 @@ class PagedEngine:
         out of the swapped_out/in event counters."""
         devs = jnp.asarray([d for d, _ in pairs], jnp.int32)
         hosts = [h for _, h in pairs]
-        self.h_k_pages[:, hosts] = np.asarray(self.k_pages[:, devs])
-        self.h_v_pages[:, hosts] = np.asarray(self.v_pages[:, devs])
+        tr = self.trace
+        with tr.span("engine", "sync") if tr is not None else NO_SPAN:
+            self.h_k_pages[:, hosts] = np.asarray(self.k_pages[:, devs])
+            self.h_v_pages[:, hosts] = np.asarray(self.v_pages[:, devs])
 
     def _spill_in_copy(self, pairs) -> None:
         hosts = [h for h, _ in pairs]

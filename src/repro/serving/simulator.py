@@ -516,7 +516,10 @@ class SimBackend:
                 m.gauge("swap_pending_pages",
                         self.allocator.pending_out_pages)
             if self.prefix_cache is not None:
-                m.gauge("prefix_hit_rate", self.prefix_cache.hit_rate)
+                # cumulative: a reader takes the window's differences
+                m.counters["prefix_hit_tokens"] = self.prefix_cache.hit_tokens
+                m.counters["prefix_lookup_tokens"] = \
+                    self.prefix_cache.lookup_tokens
             m.count("tokens", plan.token_count())
             m.count("decode_tokens", len(plan.decode))
             m.count("prefill_tokens", sum(c.length for c in plan.chunks))
@@ -525,7 +528,6 @@ class SimBackend:
             m.count("swap_ins", len(plan.swap_in))
             m.count("swap_issues", len(plan.swap_issue))
             m.count("swap_cancels", len(plan.swap_cancel))
-            m.observe("iteration_time_s", self._now - t_start)
             m.snapshot(self._now, self.iterations)
         self.iterations += 1
         self.peak_memory_frac = max(

@@ -12,13 +12,17 @@ when hypothesis is installed, over random router policies/share modes):
   ``plan.token_count()``).
 """
 
+import dataclasses
 import json
+import time
 import tracemalloc
 from collections import Counter, defaultdict
 
+import numpy as np
+import pytest
 from hypothesis_compat import given, settings, st
 
-from repro.core.telemetry import (Tracer, merge_events, percentile,
+from repro.core.telemetry import (NO_SPAN, Tracer, merge_events, percentile,
                                   to_chrome_trace, validate_trace_events)
 from repro.serving.simulator import (SimBackend, make_shared_prefix_workload,
                                      make_workload, simulate_paged,
@@ -228,6 +232,19 @@ def test_metrics_csv_and_json_export(tmp_path):
     assert len(rows) == n and rows[0]["instance"] == 0
 
 
+def test_sim_timeline_counts_prefix_tokens_cumulatively():
+    res = simulate_paged(make_shared_prefix_workload(40, rate=40.0, seed=3),
+                         num_blocks=3000, prefix_cache=True, trace=True)
+    rows = res.timelines[0]
+    hits = [r["prefix_hit_tokens"] for r in rows]
+    looks = [r["prefix_lookup_tokens"] for r in rows]
+    assert all("prefix_hit_rate" not in r for r in rows)
+    assert hits == sorted(hits) and looks == sorted(looks)
+    # a window's hit rate is the ratio of the counters' differences
+    assert hits[-1] / looks[-1] == pytest.approx(res.prefix_hit_rate)
+    assert hits[-1] > 0
+
+
 def test_tracer_ring_buffer_overwrites_oldest():
     tr = Tracer(capacity=4)
     for i in range(10):
@@ -290,3 +307,186 @@ def test_backend_telemetry_attrs_default_none():
     b = SimBackend(num_blocks=100)
     assert b.trace is None and b.metrics is None
     assert b.scheduler.trace is None
+
+
+# ---------------------------------------------------------------- spans
+
+
+def test_span_pushes_a_complete_event_on_a_wall_clock_tracer():
+    tr = Tracer()
+    tr.now, tr.t_step0, tr.iteration = 5.0, time.monotonic(), 7
+    with tr.span("sched", "schedule", rid=3, a=1) as args:
+        args["b"] = 2
+    (ev,) = tr.events()
+    assert (ev.cat, ev.name, ev.ph, ev.rid, ev.it) == \
+        ("sched", "schedule", "X", 3, 7)
+    assert ev.args == {"a": 1, "b": 2}
+    assert ev.ts >= 5.0 and ev.dur >= 0.0
+
+
+def test_span_on_a_virtual_clock_tracer_pushes_nothing():
+    tr = Tracer(clock=lambda: 1.0)
+    with tr.span("prefix", "match", k=1) as args:
+        assert args == {"k": 1}
+    assert len(tr) == 0 and tr.emitted == 0
+
+
+def test_nested_spans_keep_their_order():
+    tr = Tracer()
+    tr.now, tr.t_step0 = 0.0, time.monotonic()
+    with tr.span("engine", "iteration"):
+        with tr.span("sched", "schedule"):
+            pass
+        with tr.span("engine", "sync"):
+            time.sleep(0.001)
+    by = {e.name: e for e in tr.events()}
+    # pushed at exit: inner spans first
+    assert [e.name for e in tr.events()] == ["schedule", "sync", "iteration"]
+    it, sch, syn = by["iteration"], by["schedule"], by["sync"]
+    assert it.ts <= sch.ts <= sch.ts + sch.dur <= syn.ts
+    assert syn.ts + syn.dur <= it.ts + it.dur
+    assert syn.dur >= 0.001
+
+
+def test_disabled_site_enters_the_shared_null_span():
+    assert NO_SPAN.__enter__() is None
+    with NO_SPAN as args:
+        assert args is None
+
+
+# ------------------------------------------------------- the paged engine
+
+
+@pytest.fixture(scope="module")
+def tiny_engine_setup():
+    import jax
+    from repro.configs import smoke_config
+    from repro.models import Model
+    cfg = dataclasses.replace(smoke_config("h2o-danube-1.8b"),
+                              sliding_window=None)
+    params = Model(cfg, remat=False).init(jax.random.PRNGKey(0))
+    return cfg, params
+
+
+def _tiny_engine(setup, telemetry):
+    from repro.serving.engine import EngineConfig, PagedEngine
+    cfg, params = setup
+    return PagedEngine(cfg, params, EngineConfig(
+        num_pages=64, page_size=8, max_slots=4, max_tokens_per_iter=32,
+        enable_prefix_cache=True, enable_telemetry=telemetry))
+
+
+def _serve(eng, seed, n=3):
+    """Requests sharing a two-page prefix, through LLMService."""
+    from repro.serving.api import LLMService, SamplingParams
+    rng = np.random.default_rng(seed)
+    shared = rng.integers(1, 200, 16).tolist()
+    prompts = [shared + rng.integers(1, 200, 5 + 3 * i).tolist()
+               for i in range(n)]
+    svc = LLMService(eng)
+    return svc, svc.generate(prompts, SamplingParams(max_new_tokens=3))
+
+
+def test_disabled_tracer_constructs_nothing_in_the_engine(tiny_engine_setup):
+    """The same zero-allocation guarantee on a CPU PagedEngine stepping
+    through LLMService with the prefix cache on and telemetry off."""
+    import repro.core.telemetry.metrics as metrics_mod
+    import repro.core.telemetry.tracer as tracer_mod
+    eng = _tiny_engine(tiny_engine_setup, telemetry=False)
+    assert eng.trace is None and eng.prefix_cache.trace is None
+    _serve(eng, seed=0)  # compile every shape outside the measurement
+    flt = [tracemalloc.Filter(True, m.__file__)
+           for m in (tracer_mod, metrics_mod)]
+    tracemalloc.start(5)
+    try:
+        _serve(eng, seed=1)
+        snap = tracemalloc.take_snapshot().filter_traces(flt)
+    finally:
+        tracemalloc.stop()
+    leaked = sum(s.size for s in snap.statistics("filename"))
+    assert leaked == 0, f"disabled engine path allocated {leaked} bytes " \
+                        f"inside the telemetry layer"
+
+
+def test_engine_spans_nest_on_one_host_line_of_a_profiler_trace(
+        tiny_engine_setup, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    eng = _tiny_engine(tiny_engine_setup, telemetry=True)
+    _serve(eng, seed=0)  # compile outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        _serve(eng, seed=1)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = ("engine.iteration", "sched.schedule", "engine.sync",
+             "sched.complete", "service.poll", "prefix.match",
+             "prefix.insert")
+    lines = [line for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             if any(e.name == "engine.iteration" for e in line.events)]
+    assert len(lines) == 1
+    evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+           for e in lines[0].events if e.name in names]
+    assert {n for n, _, _ in evs} == set(names)
+    decoding = 0
+    for name, a, b in evs:
+        if name != "engine.iteration":
+            continue
+        kids = sorted((s, n) for n, s, e in evs
+                      if a <= s and e <= b and n != "engine.iteration"
+                      and not n.startswith("prefix."))
+        order = [n for _, n in kids]
+        assert order[0] == "sched.schedule" and order[-1] == "sched.complete"
+        if "engine.sync" in order:
+            decoding += 1
+            assert order.index("engine.sync") > 0
+        # every iteration lies inside one service.poll
+        assert any(n == "service.poll" and s <= a and b <= e
+                   for n, s, e in evs)
+    assert decoding > 0
+
+
+def test_engine_ring_keeps_iteration_and_chunk_events(tiny_engine_setup):
+    eng = _tiny_engine(tiny_engine_setup, telemetry=True)
+    svc, outs = _serve(eng, seed=2)
+    evs = eng.trace.events()
+    iters = [e for e in evs if e.cat == "engine" and e.name == "iteration"]
+    chunks = [e for e in evs if e.cat == "engine" and e.name == "chunk"]
+    assert len(iters) == eng.iterations
+    assert all(set(e.args) == {"tokens", "decodes", "chunks"} for e in iters)
+    assert sum(e.args["chunks"] for e in iters) == len(chunks)
+    for e in chunks:
+        assert set(e.args) == {"start", "length", "last"}
+        assert e.rid is not None and e.dur >= 0.0
+    # every span is a ring event too
+    assert {(e.cat, e.name) for e in evs if e.ph == "X"} >= {
+        ("engine", "sync"), ("sched", "schedule"), ("sched", "complete"),
+        ("prefix", "match"), ("prefix", "insert")}
+    assert eng.trace.dropped == 0
+    # the timeline keeps the cumulative hit and lookup tokens
+    rows = svc.stats().timelines[0]
+    last = rows[-1]
+    assert "prefix_hit_rate" not in last
+    assert last["prefix_hit_tokens"] == eng.prefix_cache.hit_tokens > 0
+    assert last["prefix_lookup_tokens"] == eng.prefix_cache.lookup_tokens
+
+
+def test_tokens_are_stamped_when_they_reach_the_host(tiny_engine_setup):
+    from repro.core.scheduling.request import Request
+    eng = _tiny_engine(tiny_engine_setup, telemetry=True)
+    req = Request(0, 0.0, list(range(1, 12)), max_new_tokens=3)
+    eng.add_request(req)
+    now = 100.0
+    eng.step(now)  # the whole prompt in one chunk: the first token
+    syncs = [e for e in eng.trace.events()
+             if e.name == "sync" and e.it == 0]
+    assert syncs and req.first_token_time is not None
+    # at least the step's start plus the host time up to the token's sync
+    assert req.first_token_time >= max(e.ts + e.dur for e in syncs) > now
+    assert req.last_token_time == req.first_token_time
+    eng.step(now + 1.0)
+    assert req.last_token_time > now + 1.0
+    assert req.max_tbt == req.last_token_time - req.first_token_time

@@ -15,12 +15,14 @@ balanced async begin/end spans — plus the host-swap invariant
 one unmatched trailing swap_out. Exit status is non-zero on any problem.
 
 ``--check-disabled-overhead`` runs the chunked-prefill sim path twice —
-telemetry off, then on — and asserts with ``tracemalloc`` that the
-disabled run allocates ZERO bytes attributable to the telemetry module
-files: with ``trace=False`` every emission site is a single ``None``
-attribute test, so no Event object, args dict, or string may be
-constructed. (A wall-clock <2% bound is reported for information but not
-enforced — CI machines are too noisy to gate on sub-percent timing.)
+telemetry off, then on — and a tiny CPU ``PagedEngine`` (prefix cache on)
+through ``LLMService`` with telemetry off, and asserts with
+``tracemalloc`` that the disabled runs allocate ZERO bytes attributable to
+the telemetry module files: with the tracer ``None`` every emission site
+is a single attribute test (a span site enters the shared ``NO_SPAN``),
+so no Event object, args dict, or string may be constructed. (A
+wall-clock <2% bound is reported for information but not enforced — CI
+machines are too noisy to gate on sub-percent timing.)
 """
 
 from __future__ import annotations
@@ -69,19 +71,52 @@ def _sim_run(trace: bool):
                           max_tokens_per_iter=512, trace=trace)
 
 
-def check_disabled_overhead() -> int:
+def _engine_serve():
+    """A tiny CPU engine with the prefix cache on and telemetry off, and a
+    function serving requests that share a prefix through LLMService."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+    from repro.configs import smoke_config
+    from repro.models import Model
+    from repro.serving.api import LLMService, SamplingParams
+    from repro.serving.engine import EngineConfig, PagedEngine
+    cfg = dataclasses.replace(smoke_config("h2o-danube-1.8b"),
+                              sliding_window=None)
+    params = Model(cfg, remat=False).init(jax.random.PRNGKey(0))
+    eng = PagedEngine(cfg, params, EngineConfig(
+        num_pages=64, page_size=8, max_slots=4, max_tokens_per_iter=32,
+        enable_prefix_cache=True))
+
+    def serve(seed):
+        rng = np.random.default_rng(seed)
+        shared = rng.integers(1, 200, 16).tolist()
+        LLMService(eng).generate(
+            [shared + rng.integers(1, 200, 5 + 3 * i).tolist()
+             for i in range(3)], SamplingParams(max_new_tokens=3))
+    return serve
+
+
+def _telemetry_bytes(fn) -> int:
+    """Bytes ``fn()`` allocates in the telemetry module files."""
     import repro.core.telemetry.tracer as tracer_mod
     import repro.core.telemetry.metrics as metrics_mod
-
-    _sim_run(False)  # warm imports/caches outside the measured window
-
     telemetry_files = (tracer_mod.__file__, metrics_mod.__file__)
     flt = [tracemalloc.Filter(True, f) for f in telemetry_files]
     tracemalloc.start(5)
-    _sim_run(False)
+    fn()
     snap = tracemalloc.take_snapshot().filter_traces(flt)
     tracemalloc.stop()
-    telemetry_bytes = sum(st.size for st in snap.statistics("filename"))
+    return sum(st.size for st in snap.statistics("filename"))
+
+
+def check_disabled_overhead() -> int:
+    _sim_run(False)  # warm imports/caches outside the measured window
+    telemetry_bytes = _telemetry_bytes(lambda: _sim_run(False))
+    serve = _engine_serve()
+    serve(0)  # compile every shape outside the measured window
+    engine_bytes = _telemetry_bytes(lambda: serve(1))
 
     # time outside the tracemalloc window — it slows every allocation
     t0 = time.perf_counter()
@@ -93,10 +128,12 @@ def check_disabled_overhead() -> int:
 
     print(f"tracer-disabled run: {telemetry_bytes} bytes allocated by "
           f"telemetry code (must be 0)")
+    print(f"tracer-disabled engine run: {engine_bytes} bytes allocated by "
+          f"telemetry code (must be 0)")
     print(f"wall time: disabled {t_off * 1e3:.1f}ms, enabled "
           f"{t_on * 1e3:.1f}ms ({len(res_on.events)} events) "
           f"[informational]")
-    if telemetry_bytes != 0:
+    if telemetry_bytes or engine_bytes:
         print("FAIL: the disabled path constructed telemetry objects")
         return 1
     print("OK: disabled path allocates nothing in the telemetry layer")
@@ -108,8 +145,8 @@ def main() -> int:
     ap.add_argument("traces", nargs="*",
                     help="trace-event JSON files to validate")
     ap.add_argument("--check-disabled-overhead", action="store_true",
-                    help="assert the tracer-disabled sim path allocates "
-                         "nothing in the telemetry layer")
+                    help="assert the tracer-disabled sim and engine paths "
+                         "allocate nothing in the telemetry layer")
     args = ap.parse_args()
     if not args.traces and not args.check_disabled_overhead:
         ap.error("nothing to do: pass trace files and/or "
