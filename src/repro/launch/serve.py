@@ -158,9 +158,11 @@ def main():
     ap.add_argument("--top-p", type=float, default=1.0)
     ap.add_argument("--best-of", type=int, default=1,
                     help="n parallel samples per prompt (COW-forked KV)")
-    ap.add_argument("--use-kernel", action="store_true",
-                    help="Pallas paged-attention kernel for decode (Mosaic on "
-                         "a TPU, interpret mode on the CPU)")
+    ap.add_argument("--use-kernel", action=argparse.BooleanOptionalAction,
+                    default=None,
+                    help="force the Pallas paged-attention kernel for GQA "
+                         "decode on or off (default: on where the pools live "
+                         "on a TPU; interpret mode on the CPU)")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="radix-tree prefix KV cache (cross-request reuse)")
     from repro.core.scheduling import CHUNK_POLICIES

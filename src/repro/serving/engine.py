@@ -20,8 +20,9 @@ Execution model per iteration (continuous batching):
    boundary;
 3. all running sequences advance one token in a single batched decode step
    over fixed slots — attention reads scattered pages via the block table
-   (by default the models' own ``paged_decode_attention`` in pure XLA; the
-   Pallas ``repro.kernels.paged_attention`` kernel via ``use_kernel``), and
+   (GQA on a TPU: the Pallas ``repro.kernels.paged_attention`` kernel,
+   which reads only each slot's live pages; elsewhere the models' own
+   ``paged_decode_attention`` in pure XLA; ``use_kernel`` forces one), and
    sampling runs **fused with vectorized per-slot parameters**: each slot
    applies its own request's temperature / top-k / top-p / seed
    (``repro.models.sampling.sample_batch``), and stop/eos/length finish
@@ -72,6 +73,7 @@ from repro.core.scheduling.iteration import IterationScheduler
 from repro.core.scheduling.request import Phase, Request
 from repro.core.telemetry import NO_SPAN, MetricsRegistry, Tracer
 from repro.kernels import ops
+from repro.kernels.paged_attention import live_pages
 from repro.models import Model
 from repro.models import moe as moe_mod
 from repro.models import sampling
@@ -99,7 +101,10 @@ class EngineConfig:
     page_size: int = 16
     max_slots: int = 8
     max_tokens_per_iter: int = 2048
-    use_kernel: bool = False  # True => Pallas paged_attention (interpret on CPU)
+    # GQA decode attention: None => the Pallas paged_attention kernel where
+    # the pools live on a TPU, the XLA gather elsewhere; True / False force
+    # one (True on the CPU runs the kernel in interpret mode)
+    use_kernel: Optional[bool] = None
     # DEPRECATED: per-request SamplingParams (serving.api) supersede the
     # engine-global temperature; this only seeds the default params applied
     # to requests submitted without `sampling` set.
@@ -182,6 +187,8 @@ class PagedEngine:
         (self.device,) = jax.tree.leaves(params)[0].devices()
         self.k_pages = jnp.zeros(shape_a, cfg.param_dtype, device=self.device)
         self.v_pages = jnp.zeros(shape_b, cfg.param_dtype, device=self.device)
+        self.use_kernel = ecfg.use_kernel if ecfg.use_kernel is not None \
+            else self.flavor == "gqa" and self.device.platform == "tpu"
         self.allocator = BlockAllocator(P, ps,
                                         host_blocks=ecfg.host_pages,
                                         layout=self.kv_layout)
@@ -471,10 +478,13 @@ class PagedEngine:
         tokens: (n,), positions: (n,), block_tables: (n, max_pages),
         ctx_lens: (n,) (0 = inactive slot). Returns (logits (n, V), pages).
 
-        GQA runs the models' paged decode attention or, with
-        ``use_kernel``, the Pallas paged-attention kernel; MLA gathers
-        the latent pools and attends in the matrix-absorbed effective
-        single-kv-head form (the Pallas kernel is GQA-shaped)."""
+        GQA runs the Pallas paged-attention kernel where ``use_kernel``
+        resolves to it (the default on a TPU: it reads only each slot's
+        live pages) and otherwise the models' paged decode attention, which
+        gathers the whole block table. MLA gathers the latent pools and
+        attends in the matrix-absorbed effective single-kv-head form (the
+        Pallas kernel is GQA-shaped); :meth:`_decode_zc_fn` gathers the
+        whole table too, in either flavor: neither has a kernel path yet."""
         cfg = self.cfg
         ecfg = self.ecfg
         n = tokens.shape[0]
@@ -525,7 +535,7 @@ class PagedEngine:
                         k[:, 0].astype(kp.dtype))
                     vp2 = vp.at[page_slot, in_page].set(
                         v[:, 0].astype(vp.dtype))
-                    att_fn = ops.paged_attention if ecfg.use_kernel \
+                    att_fn = ops.paged_attention if self.use_kernel \
                         else paged_decode_attention
                     att = att_fn(q[:, 0], kp2, vp2, block_tables, ctx_lens,
                                  page_size=ps, window=window)
@@ -961,6 +971,15 @@ class PagedEngine:
                     self.params, self.k_pages, self.v_pages,
                     jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
                     jnp.asarray(lens))
+                if self.metrics is not None:
+                    # cumulative: the pages inside each active slot's
+                    # context and window (what the kernel reads) against
+                    # the block table's width (what the XLA gather reads)
+                    _, live = live_pages(lens, ps, self._window)
+                    self.metrics.count("decode_live_pages", int(live.sum()))
+                    self.metrics.count(
+                        "decode_table_pages",
+                        len(decode_reqs) * self.max_pages_per_seq)
             sampled, lps = self._sample_rows(logits, row_reqs)
             t_host = now + (time.monotonic() - t0)
             for req in decode_reqs:

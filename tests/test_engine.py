@@ -136,16 +136,63 @@ def test_bf16_divergence_is_argmax_tie_artifact(model_setup, model_setup_f32):
     # fine — the float32 half still proves the comparison is exact)
 
 
-def test_engine_pallas_kernel_path(model_setup):
+@pytest.mark.parametrize("window,prompts,max_new", [
+    # global attention: one request, the other slot never active
+    (None, [[5, 9, 2, 7]], [3]),
+    # the 64-token sliding window with the context past it; the second
+    # request finishes first, so its slot sits inactive in later steps
+    (64, [list(range(3, 73)), [11, 4, 6]], [5, 1]),
+])
+def test_engine_pallas_kernel_path(model_setup_f32, window, prompts,
+                                   max_new):
     """Same engine with the Pallas paged-attention kernel (interpret)."""
-    cfg, model, params = model_setup
+    cfg, model, params = model_setup_f32
+    if window is not None:
+        cfg = dataclasses.replace(cfg, sliding_window=window)
+        model = Model(cfg, remat=False)
     eng = PagedEngine(cfg, params, EngineConfig(num_pages=32, page_size=8,
                                                 max_slots=2, use_kernel=True))
-    r = Request(0, 0.0, [5, 9, 2, 7], max_new_tokens=3)
-    eng.add_request(r)
+    reqs = [Request(i, 0.0, p, max_new_tokens=n)
+            for i, (p, n) in enumerate(zip(prompts, max_new))]
+    for r in reqs:
+        eng.add_request(r)
     eng.run_to_completion()
-    want = _oracle(model, params, cfg, r.prompt, 3)
-    assert r.full_output == want
+    for r in reqs:
+        assert r.full_output == _oracle(model, params, cfg, r.prompt,
+                                        r.max_new_tokens)
+
+
+def test_engine_kernel_default_follows_device(model_setup):
+    """``use_kernel=None`` takes the kernel only where the pools live on a
+    TPU: on a CPU device it resolves to the XLA gather."""
+    cfg, model, params = model_setup
+    eng = PagedEngine(cfg, params, EngineConfig(num_pages=16, page_size=8,
+                                                max_slots=2))
+    assert eng.device.platform == "cpu"
+    assert eng.use_kernel is False
+    forced = PagedEngine(cfg, params, EngineConfig(
+        num_pages=16, page_size=8, max_slots=2, use_kernel=True))
+    assert forced.use_kernel is True
+
+
+def test_engine_counts_live_decode_pages(model_setup_f32):
+    """With telemetry on, the engine counts the pages each decode step's
+    active slots hold inside their context and window, against the block
+    table's width."""
+    cfg, model, params = model_setup_f32
+    cfg = dataclasses.replace(cfg, sliding_window=64)
+    eng = PagedEngine(cfg, params, EngineConfig(
+        num_pages=32, page_size=8, max_slots=2, enable_telemetry=True))
+    assert eng.max_pages_per_seq == 32
+    eng.add_request(Request(0, 0.0, list(range(3, 73)), max_new_tokens=4))
+    eng.add_request(Request(1, 0.0, [4, 5, 6], max_new_tokens=2))
+    eng.run_to_completion()
+    # decode contexts: request 0 at 71, 72, 73 tokens (window 64: pages
+    # 0-8, 1-8, 1-9 -> 9, 8, 9 live); request 1 at 4 tokens in the first
+    # step only (page 0 -> 1 live)
+    c = eng.metrics.counters
+    assert c["decode_live_pages"] == 9 + 1 + 8 + 9
+    assert c["decode_table_pages"] == (2 + 1 + 1) * 32
 
 
 def test_engine_swa_arch(model_setup):

@@ -6,6 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.paged_attention import (NEG_INF, live_pages,
+                                          pages_per_block)
 
 
 def _tol(dtype):
@@ -53,6 +55,88 @@ def test_paged_attention_sliding_window(window):
     want = ref.paged_attention_ref(q, kp, vp, bt, lens, page_size=ps,
                                    window=window)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+
+
+def _paged_case(seed, b, hkv, g, dh, ps, pages_per_seq, dtype=jnp.float32):
+    """Random q and pools, and block tables of distinct physical pages."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    npages = b * pages_per_seq + 3
+    q = jax.random.normal(ks[0], (b, hkv * g, dh), dtype)
+    kp = jax.random.normal(ks[1], (npages, ps, hkv, dh), dtype)
+    vp = jax.random.normal(ks[2], (npages, ps, hkv, dh), dtype)
+    perm = np.random.default_rng(seed).permutation(npages)
+    bt = jnp.asarray(perm[:b * pages_per_seq].reshape(b, pages_per_seq),
+                     jnp.int32)
+    return q, kp, vp, bt
+
+
+@pytest.mark.parametrize(
+    "b,hkv,g,dh,ps,pages_per_seq,window,lens",
+    [
+        # 8 pages per block: contexts a multiple of neither the page nor
+        # the block, one of two blocks; an empty slot
+        (3, 2, 4, 80, 8, 12, None, [0, 37, 93]),
+        (3, 2, 12, 128, 8, 12, None, [1, 0, 96]),
+        # a window that cuts mid-page, over one block or two
+        (3, 2, 4, 80, 4, 12, 45, [5, 47, 30]),
+        (3, 2, 12, 128, 16, 8, 40, [0, 127, 17]),
+        # 4 pages per block (6-page tables); a window of one token
+        (2, 2, 4, 80, 8, 6, 1, [48, 9]),
+    ])
+def test_paged_attention_live_pages(b, hkv, g, dh, ps, pages_per_seq,
+                                    window, lens):
+    """The kernel reads each slot's live pages block by block and matches
+    the reference on every slot with context; empty slots give zeros and
+    empty partials."""
+    q, kp, vp, bt = _paged_case(sum(lens), b, hkv, g, dh, ps, pages_per_seq)
+    lens = jnp.asarray(lens, jnp.int32)
+    out, m, l = ops.paged_attention(q, kp, vp, bt, lens, page_size=ps,
+                                    window=window, return_partials=True)
+    want = ref.paged_attention_ref(q, kp, vp, bt, lens, page_size=ps,
+                                   window=window)
+    act = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(out)[act], np.asarray(want)[act],
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(out)[~act].any()
+    assert np.all(np.asarray(l)[~act] == 0)
+    assert np.all(np.asarray(m)[~act] == NEG_INF)
+
+
+def test_pages_per_block_from_shapes():
+    """256 KiB of one pool per block: 8 pages at both benchmark widths,
+    never more than half the table."""
+    assert pages_per_block(16, 8, 80, 2, 1024) == 8     # h2o-danube-1.8b
+    assert pages_per_block(16, 8, 128, 2, 512) == 8     # Mistral-Large
+    assert pages_per_block(8, 2, 80, 4, 12) == 8
+    assert pages_per_block(8, 2, 80, 4, 6) == 4
+    assert pages_per_block(16, 8, 128, 4, 512) == 4
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_never_reads_dead_pages(dtype):
+    """Every pool page outside each slot's live range (past its context,
+    before its window, in no table, the whole table of an empty slot) is
+    NaN: an output that stays finite and equal to the reference proves
+    the kernel never reads those pages."""
+    b, hkv, g, dh, ps, nper, window = 4, 2, 4, 80, 8, 12, 27
+    q, kp, vp, bt = _paged_case(7, b, hkv, g, dh, ps, nper, dtype)
+    lens = jnp.asarray([45, 0, 96, 3], jnp.int32)
+    first, count = live_pages(np.asarray(lens), ps, window)
+    live = np.zeros(kp.shape[0], bool)
+    for i in range(b):
+        live[np.asarray(bt)[i, first[i]:first[i] + count[i]]] = True
+    assert live.sum() == count.sum() < kp.shape[0] // 2
+    dead = jnp.asarray(~live)[:, None, None, None]
+    out = ops.paged_attention(q, jnp.where(dead, jnp.nan, kp),
+                              jnp.where(dead, jnp.nan, vp), bt, lens,
+                              page_size=ps, window=window)
+    want = ref.paged_attention_ref(q, kp, vp, bt, lens, page_size=ps,
+                                   window=window)
+    out = np.asarray(out, np.float32)
+    assert np.all(np.isfinite(out))
+    act = np.asarray(lens) > 0
+    np.testing.assert_allclose(out[act], np.asarray(want, np.float32)[act],
+                               **_tol(dtype))
 
 
 def test_paged_attention_partials_merge():

@@ -5,7 +5,9 @@ Interpret-mode tests (``test_kernels.py``) cannot see what Mosaic refuses —
 unaligned tiles, too much VMEM, an unpartitionable grid — so these compile
 ``paged_attention`` with ``interpret=False`` at h2o-danube-1.8b's decode
 shapes: 8 slots, 32 query heads over 8 KV heads, head_dim 80, 16-token
-pages, 1024 pages per sequence (its 16k context), 2049 pool pages.
+pages, 1024 pages per sequence (its 16k context), 2049 pool pages; and at
+the benchmark's serving widths: danube with 16 slots and 4097 pool pages,
+and Mistral-Large-2407's 96 query heads over 8 KV heads at head_dim 128.
 
 The topology is described inside a fixture, never at import: the process
 that describes it loads the TPU library and keeps it until it exits.
@@ -70,6 +72,32 @@ def test_paged_attention_compiles_for_v5e(one_chip, window,
         page_size=PAGE_SIZE, window=window, return_partials=return_partials,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not XLA
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("slots,heads,kv_heads,head_dim,pages_per_seq,"
+                         "window,pool_pages", [
+    # h2o-danube-1.8b as the benchmark serves it: 16 slots, 16k context,
+    # its 4096-token window, 4096 pool pages
+    (16, 32, 8, 80, 1024, 4096, 4097),
+    # Mistral-Large-2407's attention: 96 query heads over 8 KV heads,
+    # head_dim 128, an 8k context, 8192 pool pages
+    (16, 96, 8, 128, 512, None, 8193),
+])
+def test_paged_attention_compiles_at_serving_width(
+        one_chip, slots, heads, kv_heads, head_dim, pages_per_seq, window,
+        pool_pages):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = spec((pool_pages, PAGE_SIZE, kv_heads, head_dim), jnp.bfloat16)
+    compiled = paged_attention.lower(
+        spec((slots, heads, head_dim), jnp.bfloat16), pool, pool,
+        spec((slots, pages_per_seq), jnp.int32), spec((slots,), jnp.int32),
+        page_size=PAGE_SIZE, window=window, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes < V5E_HBM_BYTES
