@@ -24,7 +24,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from perfbench import generator, roofline, spec, tracefile
+from perfbench import generator, published, roofline, spec, tracefile
 from perfbench.stats import percentile
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -47,7 +47,11 @@ class Rec:
 
 @dataclasses.dataclass
 class Run:
-    """The record a run leaves for the metric readers."""
+    """The record a run leaves for the metric readers. ``counters`` holds
+    the window's change of every counter in the engine's telemetry registry
+    (telemetry is on in traced runs) and of the prefix cache's
+    ``prefix_hit_tokens`` and ``prefix_lookup_tokens``; ``traced_events``
+    every ring event of the traced iterations, in emission order."""
 
     model: Dict
     peaks: Dict
@@ -59,6 +63,7 @@ class Run:
     trace: Optional[tracefile.Trace] = None
     traced_decodes: List[List[int]] = dataclasses.field(default_factory=list)
     traced_chunks: List = dataclasses.field(default_factory=list)
+    traced_events: List = dataclasses.field(default_factory=list)
     admits: Dict[int, float] = dataclasses.field(default_factory=dict)
 
 
@@ -100,24 +105,13 @@ def start_jax():
 
 
 def arch_config(name: str, conf: Dict):
-    """The program's ``ArchConfig`` for a dense GQA configuration file."""
-    from repro.configs import ArchConfig
-    m = conf["model"]
-    if not m.get("tie_word_embeddings"):
-        raise ValueError("the program ties its unembedding to the embedding;"
-                         " an untied head cannot be served as stated")
-    return ArchConfig(
-        arch_id=name, family="dense", source=conf["source"],
-        num_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"],
-        head_dim=m.get("head_dim") or
-        m["hidden_size"] // m["num_attention_heads"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        attention="gqa", sliding_window=m.get("sliding_window"),
-        max_seq_len=m["max_position_embeddings"],
-        rope_theta=float(m["rope_theta"]), norm_eps=float(m["rms_norm_eps"]),
-        tie_embeddings=True, dtype=m["torch_dtype"])
+    """The program's ``ArchConfig`` for a configuration file, by the
+    program's own ``repro.configs.from_published`` where it has one, else
+    by ``published.from_published``."""
+    import repro.configs
+    translate = getattr(repro.configs, "from_published", None) or \
+        published.from_published
+    return translate(name, conf["model"], conf["source"])
 
 
 def build(name: str, conf: Dict, seed: int, telemetry: bool):
@@ -203,8 +197,8 @@ def drive(svc, engine, mix: Dict, traffic: generator.Traffic, window_s: float,
           trace_dir: Optional[str], compiles: Compiles):
     """Lead-in, window and drain. The drain sends nothing and lasts until
     every request sent in the window has had its first token, or
-    ``drain_s``. Returns (records, decode polls in the window, prefix-cache
-    counters over the window, timing marks, traced-window state)."""
+    ``drain_s``. Returns (records, decode polls in the window, counters
+    over the window, timing marks, traced-window state)."""
     import jax
     from repro.serving.api import SamplingParams
     open_loop = generator.is_open_loop(mix)
@@ -256,10 +250,15 @@ def drive(svc, engine, mix: Dict, traffic: generator.Traffic, window_s: float,
             lateness.append(t - due)
 
     def read_counters():
+        """Every counter of the engine's telemetry registry, and the
+        prefix cache's hit and lookup tokens."""
+        m = engine.metrics
+        out = {} if m is None else dict(m.counters)
         pc = engine.prefix_cache
-        return {} if pc is None else {
-            "prefix_hit_tokens": pc.hit_tokens,
-            "prefix_lookup_tokens": pc.lookup_tokens}
+        if pc is not None:
+            out.update(prefix_hit_tokens=pc.hit_tokens,
+                       prefix_lookup_tokens=pc.lookup_tokens)
+        return out
 
     if not open_loop:
         for _ in range(clients):
@@ -281,7 +280,8 @@ def drive(svc, engine, mix: Dict, traffic: generator.Traffic, window_s: float,
         if phase == "window" and t >= w1:
             phase = "drain"
             marks["w1"] = time.monotonic()
-            counters = {k: v - c0[k] for k, v in read_counters().items()}
+            counters = {k: v - c0.get(k, 0) for k, v in
+                        read_counters().items()}
             if tracing is not None:
                 traced["iters"][1] = engine.iterations
                 tracing.__exit__(None, None, None)
@@ -438,9 +438,11 @@ def run_cell(bench: spec.Bench, cell_name: str, seed: int, seconds: float,
         run.traced_decodes = traced["decodes"]
         a, b = traced["iters"]
         for ev in engine.trace.events():
-            if ev.cat == "engine" and ev.name == "chunk" and a <= ev.it < b:
-                run.traced_chunks.append((ev.args["start"],
-                                          ev.args["length"]))
+            if a <= ev.it < b:
+                run.traced_events.append(ev)
+                if ev.cat == "engine" and ev.name == "chunk":
+                    run.traced_chunks.append((ev.args["start"],
+                                              ev.args["length"]))
             if ev.cat == "sched" and ev.name == "admit" and \
                     ev.rid not in run.admits:
                 run.admits[ev.rid] = ev.ts

@@ -1,6 +1,7 @@
 """Model FLOP utilization of the engine's steps: the model FLOPs of every
-prefill chunk and decode step in the traced window (``roofline``) over
-their programs' device time times the chip's bf16 peak, in percent."""
+prefill chunk and decode step in the traced window (``roofline``; for MoE
+with each call's routing record) over their programs' device time times
+the chip's bf16 peak, in percent."""
 
 from perfbench import roofline
 from perfbench.metrics.decode_step_ms import PROGRAM as DECODE
@@ -15,9 +16,15 @@ def read(run):
     if len(dec) != len(run.traced_decodes) or \
             len(pre) != len(run.traced_chunks) or not (dec or pre):
         return None
-    flops = sum(roofline.decode_flops(run.model, c)
-                for c in run.traced_decodes) + \
-        sum(roofline.prefill_flops(run.model, s, n)
-            for s, n in run.traced_chunks)
+    dec_route = roofline.routes(run.model, run.traced_events, "decode",
+                                len(dec))
+    pre_route = roofline.routes(run.model, run.traced_events, "prefill",
+                                len(pre))
+    if dec_route is None or pre_route is None:
+        return None
+    flops = sum(roofline.decode_flops(run.model, c, r)
+                for c, r in zip(run.traced_decodes, dec_route)) + \
+        sum(roofline.prefill_flops(run.model, s, n, r)
+            for (s, n), r in zip(run.traced_chunks, pre_route))
     secs = sum(e.end - e.start for e in dec + pre)
     return 100.0 * flops / (secs * run.peaks["bf16_flops_per_s"])

@@ -1,8 +1,10 @@
 """Everything a run needs, found by name: the cell in ``BENCHMARK.json``,
-its configuration file, its traffic mix ``traffic/<mix>.json``, its
-correctness limits ``limits/<cell>.json`` and the reader of each per-layer
-metric ``metrics/<metric>.py``. Adding a cell, a mix or a metric adds
-files and entries; no file here changes."""
+its configuration file (published keys of any family that the translation
+takes, ``driver.arch_config``: dense GQA, MLA, MoE), its traffic mix
+``traffic/<mix>.json``, its correctness limits ``limits/<cell>.json`` and
+the reader of each per-layer metric ``metrics/<metric>.py``. Adding a
+configuration, a cell, a mix or a metric adds files and entries; no file
+here changes."""
 
 from __future__ import annotations
 

@@ -8,7 +8,10 @@ wherever and however it is computed: row ``r`` of leaf ``name`` in layer
 ``l`` is ``bits(fold_in(fold_in(fold_in(key(seed), crc32(name)), l), r))``.
 A leaf's scale is the power of two nearest to ``1/sqrt(fan_in)`` (the
 embedding table's to 0.02), the program's own initial scales; norm scales
-are ones and biases zeros.
+are ones and biases zeros. A leaf with more than two dimensions per layer
+is a stack of matrices (an MoE layer's experts): matrix ``i`` of its
+leading axes, counted flat, has ``i`` folded in after the layer, and its
+scale follows its fan-in ``shape[-2]``.
 """
 
 from __future__ import annotations
@@ -38,9 +41,14 @@ def leaf_key(key, name: str):
     return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
 
 
-def matrix(key, name: str, layer, rows: int, cols: int, dtype=jnp.bfloat16):
-    """Rows ``[0, rows)`` of leaf ``name`` in ``layer`` (traced or int)."""
+def matrix(key, name: str, layer, rows: int, cols: int, dtype=jnp.bfloat16,
+           index=None):
+    """Rows ``[0, rows)`` of leaf ``name`` in ``layer`` (traced or int);
+    with ``index``, of matrix ``index`` of the leaf's stack in that layer
+    (expert ``i`` of an MoE layer, alone)."""
     k = jax.random.fold_in(leaf_key(key, name), layer)
+    if index is not None:
+        k = jax.random.fold_in(k, index)
     e = scale_exp(name, (rows, cols))
     bits = jax.vmap(lambda r: jax.random.bits(
         jax.random.fold_in(k, r), (cols,), jnp.uint8))(jnp.arange(rows))
@@ -57,12 +65,27 @@ def leaf(key, name: str, shape: Tuple[int, ...], dtype, layers: int,
         return jnp.ones(shape, dtype)
     if name.endswith("/b"):
         return jnp.zeros(shape, dtype)
-    if len(per) != 2:
+    if len(per) < 2:
         raise ValueError(f"no weight rule for {name} of shape {shape}")
+
+    def one(layer):
+        if len(per) == 2:
+            return matrix(key, name, layer, *per, dtype=dtype)
+        return stack(key, name, layer, per, dtype)
+
     if not layers:
-        return matrix(key, name, first_layer, *per, dtype=dtype)
-    return jax.vmap(lambda l: matrix(key, name, l, *per, dtype=dtype))(
-        first_layer + jnp.arange(layers))
+        return one(first_layer)
+    return jax.vmap(one)(first_layer + jnp.arange(layers))
+
+
+def stack(key, name: str, layer, shape: Tuple[int, ...], dtype):
+    """A stack of matrices of ``shape`` (leading axes, rows, cols) in
+    ``layer``: matrix ``i`` of the leading axes, counted flat, is
+    ``matrix(..., index=i)``."""
+    *lead, rows, cols = shape
+    mats = jax.vmap(lambda i: matrix(key, name, layer, rows, cols, dtype,
+                                     index=i))(jnp.arange(math.prod(lead)))
+    return mats.reshape(shape)
 
 
 def _name(path) -> str:
