@@ -17,6 +17,22 @@ import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling as DeepSeek-V2 publishes it (``rope_scaling`` of
+    type ``yarn``): the rotary frequencies blend the extrapolated and the
+    interpolated ones over a ramp between the correction dimensions of
+    ``beta_fast`` and ``beta_slow`` rotations, and the softmax scale of
+    latent attention grows by ``mscale(factor, mscale_all_dim)`` squared."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     """A composable architecture description covering all assigned families."""
 
@@ -41,6 +57,7 @@ class ArchConfig:
     # Hymba-style: every Nth layer uses global attention, others sliding window.
     global_attn_every: Optional[int] = None
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YaRN] = None
     use_bias: bool = False
 
     # --- MLA (deepseek-v2) ---------------------------------------------------
@@ -56,6 +73,20 @@ class ArchConfig:
     moe_top_k: int = 0
     moe_d_ff: int = 0  # per-expert hidden size (may differ from dense d_ff)
     first_k_dense: int = 0  # first K layers use the dense MLP (deepseek-v2: 1)
+    # expert share: the router scores ``router_experts`` experts (0: as many
+    # as are held) and this device holds ``num_experts`` of them, from
+    # ``expert_offset`` on (DeepSeek-V2 on 8 chips: 20 of 160 each)
+    router_experts: int = 0
+    expert_offset: int = 0
+    # DeepSeek-V2's MoEGate: top-k over the best ``router_topk_groups`` of
+    # ``router_groups`` expert groups (1: plain top-k); normalised top-k
+    # weights, or unnormalised ones times ``routed_scale``
+    router_groups: int = 1
+    router_topk_groups: int = 1
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    # per-expert capacity of the expert-parallel training mesh path
+    # (``MeshPolicy.moe_apply``); every other path is dropless
     capacity_factor: float = 1.25
 
     # --- SSM (mamba2 SSD) ----------------------------------------------------
@@ -100,6 +131,11 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def n_router(self) -> int:
+        """Experts the router scores: all of them, held here or not."""
+        return self.router_experts or self.num_experts
 
     @property
     def is_ssm_only(self) -> bool:
@@ -185,7 +221,7 @@ def _count(cfg: ArchConfig, active_only: bool) -> int:
             e = (cfg.num_shared_experts + cfg.moe_top_k) if active_only else (
                 cfg.num_shared_experts + cfg.num_experts)
             p += e * mlp_params(cfg.moe_d_ff)
-            p += d * cfg.num_experts  # router
+            p += d * cfg.n_router  # router
         elif cfg.d_ff:
             p += mlp_params(cfg.d_ff)
         per_layer += p
@@ -262,11 +298,14 @@ def smoke_config(arch_id: str) -> ArchConfig:
         updates.update(kv_lora_rank=64, q_lora_rank=96, qk_nope_head_dim=32,
                        qk_rope_head_dim=16, v_head_dim=32)
     if cfg.is_moe:
-        # capacity_factor=E/topk => no token drops, so smoke decode matches
-        # the teacher-forced oracle exactly
+        # capacity_factor=E/topk => the expert-parallel mesh path drops no
+        # token either, so it matches the dropless single-device path;
+        # group-limited routing keeps two groups of two experts
         updates.update(num_experts=4, moe_top_k=min(cfg.moe_top_k, 2), moe_d_ff=128,
                        num_shared_experts=min(cfg.num_shared_experts, 1),
-                       capacity_factor=4.0)
+                       capacity_factor=4.0,
+                       router_groups=min(cfg.router_groups, 2),
+                       router_topk_groups=1)
     if cfg.ssm_state:
         updates.update(ssm_state=16, ssm_head_dim=32, ssm_chunk=32)
     if cfg.sliding_window:
@@ -304,3 +343,6 @@ def input_specs(cfg: ArchConfig, shape: InputShape):
         "positions": sd((b,), i32),
     }
     return out
+
+
+from repro.configs.published import from_published  # noqa: E402,F401

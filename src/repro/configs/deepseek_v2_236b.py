@@ -2,10 +2,13 @@
 
 60L d_model=5120 128H d_ff(expert)=1536 vocab=102400; MLA kv_lora_rank=512;
 2 shared + 160 routed experts, top-6; first layer dense (d_ff=12288 per the
-paper's dense-layer intermediate size).
+paper's dense-layer intermediate size). As its published config.json
+(huggingface.co/deepseek-ai/DeepSeek-V2): YaRN rotary scaling (factor 40
+over 4096 original positions), group-limited routing (the best 3 of 8
+groups), unnormalised top-6 weights times 16, an untied output head.
 """
 
-from repro.configs import ArchConfig
+from repro.configs import ArchConfig, YaRN
 
 CONFIG = ArchConfig(
     arch_id="deepseek-v2-236b",
@@ -19,6 +22,10 @@ CONFIG = ArchConfig(
     d_ff=12288,  # dense MLP hidden (first_k_dense layers)
     vocab_size=102400,
     attention="mla",
+    max_seq_len=163840,
+    rope_scaling=YaRN(factor=40.0, original_max_position=4096,
+                      beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                      mscale_all_dim=0.707),
     kv_lora_rank=512,
     q_lora_rank=1536,
     qk_nope_head_dim=128,
@@ -29,4 +36,10 @@ CONFIG = ArchConfig(
     moe_top_k=6,
     moe_d_ff=1536,
     first_k_dense=1,
+    router_groups=8,
+    router_topk_groups=3,
+    norm_topk=False,
+    routed_scale=16.0,
+    norm_eps=1e-6,
+    tie_embeddings=False,
 )

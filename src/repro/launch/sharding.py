@@ -53,8 +53,6 @@ class MeshPolicy(ShardingPolicy):
             self.dp_size *= mesh.shape[a]
         self.mp = "model" if "model" in mesh.axis_names else None
         self.mp_size = mesh.shape["model"] if self.mp else 1
-        # GShard grouped MoE dispatch: one group per data shard
-        self.moe_groups = self.dp_size
 
     # -- expert-parallel MoE via shard_map -----------------------------------
     def moe_apply(self, cfg, p, x, return_aux: bool):
@@ -64,11 +62,11 @@ class MeshPolicy(ShardingPolicy):
         shard scatter), runs its experts, and the combine is a single psum
         over ``model`` — the jax-native equivalent of the all-to-all +
         expert-compute + all-to-all pipeline, with zero GSPMD guesswork."""
-        from functools import partial
         from repro.models import moe as moe_mod
         from repro.models.layers import mlp
 
-        if not _div(cfg.num_experts, self.mp_size) or self.mp is None:
+        if not _div(cfg.num_experts, self.mp_size) or self.mp is None \
+                or cfg.n_router != cfg.num_experts:
             return None  # fall back to the jnp path
         b, s, d = x.shape
         e, k = cfg.num_experts, cfg.moe_top_k
@@ -87,10 +85,7 @@ class MeshPolicy(ShardingPolicy):
             up_w = jax.lax.all_gather(up_w, dpa, axis=1, tiled=True)
             down_w = jax.lax.all_gather(down_w, dpa, axis=1, tiled=True)
             midx = jax.lax.axis_index(self.mp)
-            logits = xt.astype(jnp.float32) @ router_w  # (T_loc, E) full E
-            probs = jax.nn.softmax(logits, axis=-1)
-            topv, topi = jax.lax.top_k(probs, k)
-            topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+            topv, topi, probs = moe_mod.route(cfg, router_w, xt)  # full E
             # position within each expert's capacity (over full E, so every
             # shard agrees on positions; cheap: (T_loc*k, E) local cumsum)
             onehot = jax.nn.one_hot(topi, e, dtype=jnp.int32)
@@ -207,12 +202,6 @@ class MeshPolicy(ShardingPolicy):
             if x.ndim == 4:
                 return self._c(x, P(batch_ax, seq_ax, None, None))
             return self._c(x, P(batch_ax, seq_ax, None))
-        if kind in ("expert_gecd", "expert_gecf"):
-            # grouped dispatch (G, E, cap, D): groups over data (they ARE the
-            # data shards), experts over model (expert parallelism)
-            gax = dp if _div(x.shape[0], self.dp_size) else None
-            eax = mp if _div(x.shape[1], self.mp_size) else None
-            return self._c(x, P(gax, eax, None, None))
         if kind == "kvrep_bshd":  # broadcast KV, flat heads (iteration 4)
             return self._c(x, P(batch_ax, None, mp, None))
         if kind == "scores_bchs":

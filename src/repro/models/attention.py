@@ -27,7 +27,7 @@ from jax import lax
 
 from repro.models.layers import (NO_POLICY, ShardingPolicy, apply_rope, dense,
                                  dense_init, mlp, norm_init, pad_last,
-                                 rms_norm)
+                                 rms_norm, yarn_mscale)
 
 # The dry-run's cost-model compiles set this so the query-chunk scan unrolls:
 # XLA's cost analysis counts a while body once regardless of trip count, so
@@ -193,8 +193,10 @@ def gqa_layer(cfg, p, x, positions, attend, *,
     roped k / raw v (B, S, Hkv, Dh), returns the attention context
     (B, S, H, Dv) plus an arbitrary carry (e.g. updated KV page buffers)
     threaded back to the caller. Layout: pre-norm, residual attention,
-    pre-norm residual MLP. ``mlp_fn(p_mlp, h) -> out`` overrides the dense
-    MLP (MoE segments pass their expert dispatch).
+    pre-norm residual MLP. ``mlp_fn(p_mlp, h) -> (out, stats)`` overrides
+    the dense MLP (MoE segments pass their expert layer and its routing
+    counts). Returns ``(y, carry, stats)``; ``stats`` is None for the dense
+    MLP.
     """
     b, s, _ = x.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -202,18 +204,27 @@ def gqa_layer(cfg, p, x, positions, attend, *,
     q = dense(p["attn"]["wq"], hn).reshape(b, s, h, dh)
     k = dense(p["attn"]["wk"], hn).reshape(b, s, hkv, dh)
     v = dense(p["attn"]["wv"], hn).reshape(b, s, hkv, dh)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta,
+                   scaling=cfg.rope_scaling)
+    k = apply_rope(k, positions, cfg.rope_theta,
+                   scaling=cfg.rope_scaling)
     q = policy.act(q, "heads_bshd")
     k = policy.act(k, "kv_bshd")
     v = policy.act(v, "kv_bshd")
     ctx, carry = attend(q, k, v)
     ctx = policy.act(ctx, "heads_bshd")
     y = x + dense(p["attn"]["wo"], ctx.reshape(b, s, -1), policy, "act_bsd")
+    return _mlp_block(cfg, p, y, policy, mlp_fn, carry)
+
+
+def _mlp_block(cfg, p, y, policy, mlp_fn, carry):
+    """The pre-norm residual MLP that closes a layer: ``(y, carry, stats)``
+    as :func:`gqa_layer` returns them."""
     h2 = rms_norm(p["ln2"], y, cfg.norm_eps)
-    y = y + (mlp(p["mlp"], h2, policy) if mlp_fn is None
-             else mlp_fn(p["mlp"], h2))
-    return y, carry
+    if mlp_fn is None:
+        return y + mlp(p["mlp"], h2, policy), carry, None
+    out, stats = mlp_fn(p["mlp"], h2)
+    return y + out, carry, stats
 
 
 def gqa_forward(cfg, p, x, positions, *, window=None, causal=True,
@@ -227,12 +238,14 @@ def gqa_forward(cfg, p, x, positions, *, window=None, causal=True,
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = dense(p["wq"], x).reshape(b, s, h, dh)
     if kv_override is None:
-        q = apply_rope(q, positions, cfg.rope_theta)  # no rope for cross-attn
+        q = apply_rope(q, positions, cfg.rope_theta,
+                       scaling=cfg.rope_scaling)  # no rope for cross-attn
     q = policy.act(q, "heads_bshd")
     if kv_override is None:
         k = dense(p["wk"], x).reshape(b, s, hkv, dh)
         v = dense(p["wv"], x).reshape(b, s, hkv, dh)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta,
+                       scaling=cfg.rope_scaling)
     else:
         k, v = kv_override
     k = policy.act(k, "kv_bshd")
@@ -290,10 +303,12 @@ def gqa_decode(cfg, p, x, cache: KVCache, pos, *, window=None,
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = dense(p["wq"], x).reshape(b, 1, h, dh)
     if kv_override is None:
-        q = apply_rope(q, pos[:, None], cfg.rope_theta)
+        q = apply_rope(q, pos[:, None], cfg.rope_theta,
+                       scaling=cfg.rope_scaling)
         knew = dense(p["wk"], x).reshape(b, 1, hkv, dh)
         vnew = dense(p["wv"], x).reshape(b, 1, hkv, dh)
-        knew = apply_rope(knew, pos[:, None], cfg.rope_theta)
+        knew = apply_rope(knew, pos[:, None], cfg.rope_theta,
+                          scaling=cfg.rope_scaling)
         slot = cache_update(cache.pos, pos)
         cache = KVCache(
             k=_write_slot(cache.k, slot, knew[:, 0]),
@@ -373,7 +388,14 @@ def _mla_q(cfg, p, x):
 
 
 def _mla_scale(cfg):
-    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    """Softmax scale of latent attention: ``(qk_nope + qk_rope)^-1/2``,
+    under YaRN times ``mscale(factor, mscale_all_dim)`` squared (1.5897
+    for DeepSeek-V2's factor 40 and 0.707)."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_forward(cfg, p, x, positions, *, policy: ShardingPolicy = NO_POLICY,
@@ -387,12 +409,14 @@ def mla_forward(cfg, p, x, positions, *, policy: ShardingPolicy = NO_POLICY,
     r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     qn, qr = _mla_q(cfg, p, x)
-    qr = apply_rope(qr, positions, cfg.rope_theta)
+    qr = apply_rope(qr, positions, cfg.rope_theta,
+                    scaling=cfg.rope_scaling)
 
     kv = dense(p["wkv_a"], x)
     ckv, krope = kv[..., :r], kv[..., r:]
     ckv = rms_norm(p["kv_norm"], ckv, cfg.norm_eps)
-    krope = apply_rope(krope, positions, cfg.rope_theta, heads=False)  # (b,s,dr) shared
+    krope = apply_rope(krope, positions, cfg.rope_theta, heads=False,
+                       scaling=cfg.rope_scaling)  # (b,s,dr) shared
     kvb = dense(p["wkv_b"], ckv).reshape(b, s, h, dn + dv)
     kn, v = kvb[..., :dn], kvb[..., dn:]
 
@@ -400,8 +424,8 @@ def mla_forward(cfg, p, x, positions, *, policy: ShardingPolicy = NO_POLICY,
     k = jnp.concatenate([kn, jnp.broadcast_to(krope[:, :, None, :], (b, s, h, dr))],
                         axis=-1)
     q = policy.act(q, "heads_bshd")
-    # blockwise_attention scales by 1/sqrt(dn+dr) via head_dim of concat — correct.
-    out = blockwise_attention(q, k, v[..., :dv], causal=True, policy=policy)
+    out = blockwise_attention(q, k, v[..., :dv], causal=True,
+                              scale=_mla_scale(cfg), policy=policy)
     y = dense(p["wo"], out.reshape(b, s, h * dv), policy, "act_bsd")
     if return_latent:
         return y, (ckv, krope)
@@ -416,11 +440,13 @@ def mla_decode(cfg, p, x, cache: MLACache, pos, *,
     r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     qn, qr = _mla_q(cfg, p, x)  # (b,1,h,dn), (b,1,h,dr)
-    qr = apply_rope(qr, pos[:, None], cfg.rope_theta)
+    qr = apply_rope(qr, pos[:, None], cfg.rope_theta,
+                    scaling=cfg.rope_scaling)
 
     kv = dense(p["wkv_a"], x)  # (b,1,r+dr)
     ckv_new = rms_norm(p["kv_norm"], kv[..., :r], cfg.norm_eps)
-    krope_new = apply_rope(kv[..., r:], pos[:, None], cfg.rope_theta, heads=False)
+    krope_new = apply_rope(kv[..., r:], pos[:, None], cfg.rope_theta, heads=False,
+                           scaling=cfg.rope_scaling)
     slot = cache_update(cache.pos, pos)
     cache = MLACache(
         ckv=_write_slot(cache.ckv, slot, ckv_new[:, 0]),
@@ -436,23 +462,33 @@ def mla_decode(cfg, p, x, cache: MLACache, pos, *,
                        w_uk.astype(jnp.bfloat16),
                        preferred_element_type=jnp.float32)
     ckv = policy.act(cache.ckv, "mlacache_bsr")
-    scores = jnp.einsum("bhr,bsr->bhs", q_lat.astype(jnp.bfloat16),
-                        ckv.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32)
-    scores += jnp.einsum("bhd,bsd->bhs", qr[:, 0].astype(jnp.bfloat16),
-                         cache.krope.astype(jnp.bfloat16),
-                         preferred_element_type=jnp.float32)
-    scores = scores * _mla_scale(cfg)
-    scores = jnp.where(mask[:, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhs,bsr->bhr", probs.astype(jnp.bfloat16),
-                     ckv.astype(jnp.bfloat16),
-                     preferred_element_type=jnp.float32)
+    ctx = mla_latent_attention(q_lat, qr[:, 0], ckv, cache.krope, mask,
+                               _mla_scale(cfg))
     out = jnp.einsum("bhr,rhd->bhd", ctx.astype(jnp.bfloat16),
                      w_uv.astype(jnp.bfloat16),
                      preferred_element_type=jnp.float32)
     y = dense(p["wo"], out.reshape(b, 1, h * dv).astype(x.dtype), policy, "act_bsd")
     return y, cache
+
+
+def mla_latent_attention(q_lat, qr, ckv, krope, mask, scale):
+    """Absorbed MLA attention of one query token per sequence over its
+    latent context, with the numerics of every attention in the models
+    (bf16 operands, fp32 accumulation and softmax): scores ``q_lat . ckv +
+    qr . krope``, values ``ckv``. Reads the latent and the rotary keys in
+    place, without lifting them to an effective key width.
+
+    q_lat: (B, H, r); qr: (B, H, dr); ckv: (B, S, r); krope: (B, S, dr);
+    mask: (B, S) bool. Returns the latent context (B, H, r) fp32."""
+    bf = jnp.bfloat16
+    scores = jnp.einsum("bhr,bsr->bhs", q_lat.astype(bf), ckv.astype(bf),
+                        preferred_element_type=jnp.float32)
+    scores += jnp.einsum("bhd,bsd->bhs", qr.astype(bf), krope.astype(bf),
+                         preferred_element_type=jnp.float32)
+    scores = jnp.where(mask[:, None, :], scores * scale, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhs,bsr->bhr", probs.astype(bf), ckv.astype(bf),
+                      preferred_element_type=jnp.float32)
 
 
 def mla_absorb(cfg, p):
@@ -508,16 +544,18 @@ def mla_layer(cfg, p, x, positions, attend_latent, *,
     ``krope_new`` (B,S,dr) (normed / pre-roped — exactly what the pools
     store); it returns the latent-space context (B,S,H,r) plus a carry
     (e.g. updated latent page buffers). The output projection absorbs W_UV.
+    ``mlp_fn`` and the return value are as in :func:`gqa_layer`.
     """
     b, s, _ = x.shape
     h, r, dv = cfg.num_heads, cfg.kv_lora_rank, cfg.v_head_dim
     hn = rms_norm(p["ln1"], x, cfg.norm_eps)
     qn, qr = _mla_q(cfg, p["attn"], hn)
-    qr = apply_rope(qr, positions, cfg.rope_theta)
+    qr = apply_rope(qr, positions, cfg.rope_theta,
+                    scaling=cfg.rope_scaling)
     kv = dense(p["attn"]["wkv_a"], hn)
     ckv_new = rms_norm(p["attn"]["kv_norm"], kv[..., :r], cfg.norm_eps)
     krope_new = apply_rope(kv[..., r:], positions, cfg.rope_theta,
-                           heads=False)
+                           heads=False, scaling=cfg.rope_scaling)
     w_uk, w_uv = mla_absorb(cfg, p["attn"])
     q_lat = jnp.einsum("bshd,rhd->bshr", qn.astype(jnp.bfloat16),
                        w_uk.astype(jnp.bfloat16),
@@ -528,10 +566,7 @@ def mla_layer(cfg, p, x, positions, attend_latent, *,
                      preferred_element_type=jnp.float32)
     y = x + dense(p["attn"]["wo"], out.reshape(b, s, h * dv).astype(x.dtype),
                   policy, "act_bsd")
-    h2 = rms_norm(p["ln2"], y, cfg.norm_eps)
-    y = y + (mlp(p["mlp"], h2, policy) if mlp_fn is None
-             else mlp_fn(p["mlp"], h2))
-    return y, carry
+    return _mlp_block(cfg, p, y, policy, mlp_fn, carry)
 
 
 def mla_prefill_cache(cfg, p, x, positions, capacity: int):
@@ -540,7 +575,8 @@ def mla_prefill_cache(cfg, p, x, positions, capacity: int):
     r = cfg.kv_lora_rank
     kv = dense(p["wkv_a"], x)
     ckv = rms_norm(p["kv_norm"], kv[..., :r], cfg.norm_eps)
-    krope = apply_rope(kv[..., r:], positions, cfg.rope_theta, heads=False)
+    krope = apply_rope(kv[..., r:], positions, cfg.rope_theta, heads=False,
+                       scaling=cfg.rope_scaling)
     pad = capacity - s
     return MLACache(
         ckv=jnp.pad(ckv, ((0, 0), (0, pad), (0, 0))),

@@ -89,21 +89,60 @@ def pad_last(x, target: int):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, scaling=None):
+    """Inverse rotary frequencies, (head_dim/2,). ``scaling`` (a
+    :class:`~repro.configs.YaRN`, or None for plain RoPE) blends them as
+    DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` does: the interpolated
+    frequencies (divided by ``factor``) below the correction range of
+    ``beta_slow`` rotations over the original context, the original ones
+    above that of ``beta_fast``, a linear ramp between."""
+    extra = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if scaling is None:
+        return extra
+
+    def correction_dim(rotations):
+        return head_dim * math.log(
+            scaling.original_max_position / (rotations * 2 * math.pi)) / \
+            (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp  # 1: the original frequency
+    return extra / scaling.factor * (1.0 - keep) + extra * keep
 
 
-def apply_rope(x, positions, theta: float, *, heads: bool = True):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature, ``0.1 * mscale * ln(factor) + 1``."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta: float, *, heads: bool = True,
+               scaling=None):
     """x: (..., S, H, D) if ``heads`` else (..., S, D).
 
     ``positions``: (S,) shared across batch, or batched (..., S).
+    ``scaling``: a :class:`~repro.configs.YaRN` or None (plain RoPE, the
+    same ops as without it); under YaRN cos and sin are also multiplied by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``.
     """
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)  # (d/2,)
+    inv = rope_freqs(d, theta, scaling)  # (d/2,)
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., S, d/2)
     if heads:
         ang = ang[..., None, :]  # broadcast over the heads axis
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / \
+            yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -145,23 +184,37 @@ def embed_init(key, vocab: int, d: int, dtype):
     return {"table": jax.random.normal(key, (v, d), dtype) * 0.02}
 
 
+def head_init(key, vocab: int, d: int, dtype):
+    """An untied output head, (D, padded V), at the embedding's scale."""
+    return {"w": jax.random.normal(key, (d, pad_vocab(vocab)), dtype) * 0.02}
+
+
 def embed(p, tokens, policy: ShardingPolicy = NO_POLICY):
     return policy.act(jnp.take(p["table"], tokens, axis=0), "act_bsd")
 
 
+def output_head(params):
+    """The unembedding's parameters: the model's own ``lm_head`` where its
+    embeddings are untied, else the embedding table."""
+    return params.get("lm_head", params["embed"])
+
+
 def unembed(p, x, vocab: int, policy: ShardingPolicy = NO_POLICY,
             fp32: bool = False):
-    """Project hidden states to vocab logits. ``fp32`` computes the
-    projection in float32: bf16 logits round near-equal candidates onto the
-    same value, so greedy argmax between two implementations can diverge on
-    the tie-break even when both are correct (ArchConfig.logits_fp32)."""
+    """Project hidden states to vocab logits through ``p``: an untied head
+    ``{"w": (D, V)}`` or the tied embedding ``{"table": (V, D)}``
+    (:func:`output_head` picks). ``fp32`` computes the projection in
+    float32: bf16 logits round near-equal candidates onto the same value,
+    so greedy argmax between two implementations can diverge on the
+    tie-break even when both are correct (ArchConfig.logits_fp32)."""
+    w = p["w"] if "w" in p else p["table"].T
     if fp32:
-        logits = x.astype(jnp.float32) @ p["table"].T.astype(jnp.float32)
+        logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
     else:
-        logits = x @ p["table"].T
+        logits = x @ w
     logits = policy.act(logits, "logits_bsv")
     # mask padded vocab entries so they never win a softmax/argmax
-    v_pad = p["table"].shape[0]
+    v_pad = w.shape[1]
     if v_pad != vocab:
         mask = jnp.arange(v_pad) < vocab
         logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)

@@ -35,8 +35,8 @@ from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (NO_POLICY, ShardingPolicy, cross_entropy,
-                                 embed, embed_init, mlp, mlp_init, norm_init,
-                                 rms_norm, unembed)
+                                 embed, embed_init, head_init, mlp, mlp_init,
+                                 norm_init, output_head, rms_norm, unembed)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,7 +124,8 @@ def _layer_forward(cfg, seg: Segment, p, x, positions, *, policy,
                                            window=window, policy=policy)
             return ctx, ((k, v) if collect_cache else None)
 
-        x, seed = attn.gqa_layer(cfg, p, x, positions, attend, policy=policy)
+        x, seed, _ = attn.gqa_layer(cfg, p, x, positions, attend,
+                                    policy=policy)
         return x, jnp.zeros((), jnp.float32), seed
     h = rms_norm(p["ln1"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
@@ -296,12 +297,15 @@ class Model:
     def init(self, key) -> Any:
         cfg = self.cfg
         dtype = cfg.param_dtype
-        k_embed, k_dec, k_enc, _ = jax.random.split(key, 4)
+        k_embed, k_dec, k_enc, k_head = jax.random.split(key, 4)
         params = {
             "embed": embed_init(k_embed, cfg.vocab_size, cfg.d_model, dtype),
             "final_norm": norm_init(cfg.d_model, dtype, bias=cfg.use_bias),
             "segments": self._init_segments(self.plan, k_dec, dtype),
         }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = head_init(k_head, cfg.vocab_size, cfg.d_model,
+                                          dtype)
         if cfg.is_encdec:
             params["encoder"] = {
                 "segments": self._init_segments(self.enc_plan, k_enc, dtype),
@@ -380,7 +384,7 @@ class Model:
         x, aux, _ = self._run_stack(self.plan, params["segments"], x,
                                     positions, policy=policy, enc_out=enc_out)
         x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.vocab_size, policy,
+        logits = unembed(output_head(params), x, cfg.vocab_size, policy,
                          fp32=cfg.logits_fp32)
         return logits, aux
 
@@ -425,8 +429,8 @@ class Model:
         if last_idx is None:
             last_idx = jnp.full((b,), s - 1, jnp.int32)
         last_h = x[jnp.arange(b), last_idx]
-        logits = unembed(params["embed"], last_h[:, None, :], cfg.vocab_size,
-                         policy, fp32=cfg.logits_fp32)
+        logits = unembed(output_head(params), last_h[:, None, :],
+                         cfg.vocab_size, policy, fp32=cfg.logits_fp32)
         if return_raw_kv:
             return logits[:, 0], seeds
         caches = self._seed_caches(seeds, b, s, seq_capacity)
@@ -503,7 +507,7 @@ class Model:
                             unroll=self._unroll(seg.n))
             new_caches.append(c)
         x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.vocab_size, policy,
+        logits = unembed(output_head(params), x, cfg.vocab_size, policy,
                          fp32=cfg.logits_fp32)
         return logits[:, 0], new_caches
 

@@ -77,11 +77,12 @@ from repro.kernels.paged_attention import live_pages
 from repro.models import Model
 from repro.models import moe as moe_mod
 from repro.models import sampling
-from repro.models.layers import embed, rms_norm, unembed
+from repro.models.layers import embed, output_head, rms_norm, unembed
 from repro.models.attention import (_mla_scale, blockwise_attention,
                                     decode_attention, gqa_layer,
                                     mla_effective_ctx, mla_effective_kv,
-                                    mla_layer, paged_decode_attention)
+                                    mla_latent_attention, mla_layer,
+                                    paged_decode_attention)
 from repro.serving.api import SamplingParams
 
 
@@ -266,6 +267,8 @@ class PagedEngine:
         else:
             self.trace = None
             self.metrics = None
+        self._moe_layers = sum(seg.n for seg in self.model.plan
+                               if seg.mlp_kind == "moe")
         self._window = cfg.sliding_window \
             if any(seg.attn_kind == "swa" for seg in self.model.plan) \
             else None
@@ -274,47 +277,76 @@ class PagedEngine:
 
     def _mlp_fn(self, seg):
         """Per-segment MLP dispatch for the shared layer bodies: dense
-        segments use the layer default, MoE segments route through the
-        expert dispatch (DeepSeek-V2's plan is 1 dense + N-1 MoE layers)."""
+        segments use the layer default, MoE segments the dropless expert
+        layer and its routing counts (DeepSeek-V2's plan is 1 dense + N-1
+        MoE layers)."""
         if seg.mlp_kind == "moe":
-            return lambda pm, h: moe_mod.moe_forward(self.cfg, pm, h)
+            return lambda pm, h: moe_mod.moe_layer(self.cfg, pm, h)[:2]
         return None
 
     def _run_segments(self, params, k_pages, v_pages, rk, rv, x, body):
-        """Thread ``x`` through every segment of the plan, slicing the
-        layer axis of both page pools (and the remote payload arrays) per
-        segment. ``body(seg, p_i, poolA, poolB, rA_i, rB_i, x) ->
-        (x, poolA', poolB')`` runs ONE layer; stacked segments (seg.n > 1)
-        ``lax.scan`` it over their stacked params + pool slices. Returns
-        (x, k_pages, v_pages) with the pools reassembled along the layer
-        axis."""
-        off = 0
-        a_parts, b_parts = [], []
-        for seg, p_seg in zip(self.model.plan, params["segments"]):
-            kp_seg = k_pages[off:off + seg.n]
-            vp_seg = v_pages[off:off + seg.n]
-            rk_seg = rk[off:off + seg.n]
-            rv_seg = rv[off:off + seg.n]
-            if seg.n == 1:
-                x, kp2, vp2 = body(seg, p_seg, kp_seg[0], vp_seg[0],
-                                   rk_seg[0], rv_seg[0], x)
-                a_parts.append(kp2[None])
-                b_parts.append(vp2[None])
-            else:
-                def scan_body(carry, scanned, seg=seg):
-                    xx, = carry
-                    p_i, kp, vp, rk_i, rv_i = scanned
-                    xx, kp2, vp2 = body(seg, p_i, kp, vp, rk_i, rv_i, xx)
-                    return (xx,), (kp2, vp2)
+        """Thread ``x`` through every segment of the plan with the layer
+        axis of both page pools (and of the remote payload arrays).
+        ``body(seg, p_i, poolA, poolB, rA_i, rB_i, x) -> (x, poolA',
+        poolB', stats)`` runs ONE layer (``stats``: the MoE layer's
+        (assigned, hit) routing counts, None for a dense MLP); stacked
+        segments (seg.n > 1) ``lax.scan`` it over their stacked params.
+        Returns (x, k_pages, v_pages, routes) with ``routes`` the (MoE
+        layers, 2) int32 counts in layer order (None without MoE layers).
 
-                (x,), (kp2, vp2) = jax.lax.scan(
-                    scan_body, (x,), (p_seg, kp_seg, vp_seg, rk_seg, rv_seg))
-                a_parts.append(kp2)
-                b_parts.append(vp2)
-            off += seg.n
-        if len(a_parts) == 1:
-            return x, a_parts[0], b_parts[0]
-        return x, jnp.concatenate(a_parts, 0), jnp.concatenate(b_parts, 0)
+        A plan of one segment scans the pools' layers as ``xs``/``ys``,
+        which XLA writes into the result in place. A plan of several (a
+        leading dense layer before MoE layers) carries the whole pools and
+        updates one layer at a time: slicing each segment's layers out and
+        concatenating the results would hold two more copies of the
+        pools."""
+        plan, segs = self.model.plan, params["segments"]
+        routes = []
+
+        def note(st, stacked):
+            if st is not None:
+                routes.append(jnp.stack(st, 1) if stacked
+                              else jnp.stack(st)[None])
+
+        if len(plan) == 1 and plan[0].n > 1:
+            seg, = plan
+
+            def scan_body(carry, scanned):
+                xx, = carry
+                p_i, kp, vp, rk_i, rv_i = scanned
+                xx, kp2, vp2, st = body(seg, p_i, kp, vp, rk_i, rv_i, xx)
+                return (xx,), (kp2, vp2, st)
+
+            (x,), (k_pages, v_pages, st) = jax.lax.scan(
+                scan_body, (x,), (segs[0], k_pages, v_pages, rk, rv))
+            note(st, True)
+        else:
+            off = 0
+            for seg, p_seg in zip(plan, segs):
+                def one(carry, scanned, seg=seg, off=off):
+                    xx, kp, vp = carry
+                    i, p_i = scanned
+                    layer = off + i
+                    xx, kp2, vp2, st = body(seg, p_i, kp[layer], vp[layer],
+                                            rk[layer], rv[layer], xx)
+                    kp = jax.lax.dynamic_update_index_in_dim(kp, kp2, layer,
+                                                             0)
+                    vp = jax.lax.dynamic_update_index_in_dim(vp, vp2, layer,
+                                                             0)
+                    return (xx, kp, vp), st
+
+                if seg.n == 1:
+                    (x, k_pages, v_pages), st = one(
+                        (x, k_pages, v_pages), (0, p_seg))
+                    note(st, False)
+                else:
+                    (x, k_pages, v_pages), st = jax.lax.scan(
+                        one, (x, k_pages, v_pages),
+                        (jnp.arange(seg.n), p_seg))
+                    note(st, True)
+                off += seg.n
+        routes = jnp.concatenate(routes, 0) if routes else None
+        return x, k_pages, v_pages, routes
 
     def _no_remote(self, dtype):
         """Zero-token remote payload arrays (one per pool) for calls
@@ -358,7 +390,9 @@ class PagedEngine:
         instead of ``2 * Hkv * Dh``.
 
         Returns (logits (V,) of the last real chunk position, k_pages,
-        v_pages); callers ignore the logits for non-final chunks. Subsumes
+        v_pages, routes); callers ignore the logits for non-final chunks;
+        ``routes`` holds each MoE layer's routing counts
+        (:meth:`_run_segments`). Subsumes
         whole-prompt prefill (start=0, one chunk) and cached-suffix prefill
         (start = cached tokens).
         """
@@ -418,10 +452,10 @@ class PagedEngine:
                                                   [l_l, l_r])
                     return ctx[..., :r_lat].astype(q_lat.dtype), (cp2, rp2)
 
-                y, (cp2, rp2) = mla_layer(cfg, p_i, xx, positions,
-                                          attend_latent,
-                                          mlp_fn=self._mlp_fn(seg))
-                return y, cp2, rp2
+                y, (cp2, rp2), st = mla_layer(
+                    cfg, p_i, xx, positions, attend_latent,
+                    mlp_fn=self._mlp_fn(seg))
+                return y, cp2, rp2, st
         else:
             def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
                 window = cfg.sliding_window if seg.attn_kind == "swa" \
@@ -457,18 +491,19 @@ class PagedEngine:
                                               [l_l, l_r])
                     return ctx.astype(q.dtype), (kp2, vp2)
 
-                y, (kp2, vp2) = gqa_layer(cfg, p_i, xx, positions, attend,
-                                          mlp_fn=self._mlp_fn(seg))
-                return y, kp2, vp2
+                y, (kp2, vp2), st = gqa_layer(
+                    cfg, p_i, xx, positions, attend,
+                    mlp_fn=self._mlp_fn(seg))
+                return y, kp2, vp2, st
 
-        x, k_pages, v_pages = self._run_segments(params, k_pages, v_pages,
-                                                 rk, rv, x, body)
+        x, k_pages, v_pages, routes = self._run_segments(
+            params, k_pages, v_pages, rk, rv, x, body)
         x = rms_norm(params["final_norm"], x, cfg.norm_eps)
         # logits of the last REAL chunk position (pad rows are garbage)
         last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-        logits = unembed(params["embed"], last, cfg.vocab_size,
+        logits = unembed(output_head(params), last, cfg.vocab_size,
                          fp32=cfg.logits_fp32)
-        return logits[0, 0], k_pages, v_pages
+        return logits[0, 0], k_pages, v_pages, routes
 
     @partial(jax.jit, static_argnums=(0,))
     def _decode_fn(self, params, k_pages, v_pages, tokens, positions,
@@ -476,15 +511,17 @@ class PagedEngine:
         """Batched one-token step over slots.
 
         tokens: (n,), positions: (n,), block_tables: (n, max_pages),
-        ctx_lens: (n,) (0 = inactive slot). Returns (logits (n, V), pages).
+        ctx_lens: (n,) (0 = inactive slot). Returns (logits (n, V), pages,
+        routes) as :meth:`_prefill_chunk_fn` does.
 
         GQA runs the Pallas paged-attention kernel where ``use_kernel``
         resolves to it (the default on a TPU: it reads only each slot's
         live pages) and otherwise the models' paged decode attention, which
-        gathers the whole block table. MLA gathers the latent pools and
-        attends in the matrix-absorbed effective single-kv-head form (the
-        Pallas kernel is GQA-shaped); :meth:`_decode_zc_fn` gathers the
-        whole table too, in either flavor: neither has a kernel path yet."""
+        gathers the whole block table. MLA gathers the latent pools' whole
+        table and attends in the matrix-absorbed form over the latent and
+        the rotary keys in place (:func:`mla_latent_attention`; the Pallas
+        kernel is GQA-shaped); :meth:`_decode_zc_fn` gathers the whole
+        table too, in either flavor: neither has a kernel path yet."""
         cfg = self.cfg
         ecfg = self.ecfg
         n = tokens.shape[0]
@@ -508,21 +545,16 @@ class PagedEngine:
                         krope_new[:, 0].astype(rp.dtype))
                     ckv_all = cp2[block_tables].reshape(n, -1, r_lat)
                     kr_all = rp2[block_tables].reshape(n, -1, dr)
-                    q_eff, k_eff, v_eff = mla_effective_kv(
-                        q_lat, qr, ckv_all.astype(q_lat.dtype),
-                        kr_all.astype(q_lat.dtype))
-                    s_loc = k_eff.shape[1]
-                    mask = (jnp.arange(s_loc)[None, :] <
-                            ctx_lens[:, None])[:, None, :]  # (n, 1, S)
-                    o, m, l = attention_partial(q_eff, k_eff, v_eff, mask,
-                                                scale=scale)
-                    ctx = merge_partials_tree([o], [m], [l])
-                    return ctx[..., :r_lat].astype(q_lat.dtype), (cp2, rp2)
+                    mask = jnp.arange(ckv_all.shape[1])[None, :] < \
+                        ctx_lens[:, None]  # (n, S)
+                    ctx = mla_latent_attention(q_lat[:, 0], qr[:, 0],
+                                               ckv_all, kr_all, mask, scale)
+                    return ctx[:, None].astype(q_lat.dtype), (cp2, rp2)
 
-                y, (cp2, rp2) = mla_layer(cfg, p_i, xx, positions[:, None],
-                                          attend_latent,
-                                          mlp_fn=self._mlp_fn(seg))
-                return y, cp2, rp2
+                y, (cp2, rp2), st = mla_layer(
+                    cfg, p_i, xx, positions[:, None], attend_latent,
+                    mlp_fn=self._mlp_fn(seg))
+                return y, cp2, rp2, st
         else:
             def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
                 window = cfg.sliding_window if seg.attn_kind == "swa" \
@@ -542,17 +574,18 @@ class PagedEngine:
                     return att.reshape(n, 1, cfg.num_heads, cfg.head_dim), \
                         (kp2, vp2)
 
-                y, (kp2, vp2) = gqa_layer(cfg, p_i, xx, positions[:, None],
-                                          attend, mlp_fn=self._mlp_fn(seg))
-                return y, kp2, vp2
+                y, (kp2, vp2), st = gqa_layer(
+                    cfg, p_i, xx, positions[:, None], attend,
+                    mlp_fn=self._mlp_fn(seg))
+                return y, kp2, vp2, st
 
         rk, rv = self._no_remote(k_pages.dtype)
-        x, k_pages, v_pages = self._run_segments(params, k_pages, v_pages,
-                                                 rk, rv, x, body)
+        x, k_pages, v_pages, routes = self._run_segments(
+            params, k_pages, v_pages, rk, rv, x, body)
         x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.vocab_size,
+        logits = unembed(output_head(params), x, cfg.vocab_size,
                          fp32=cfg.logits_fp32)[:, 0]
-        return logits, k_pages, v_pages
+        return logits, k_pages, v_pages, routes
 
     @partial(jax.jit, static_argnums=(0,))
     def _decode_zc_fn(self, params, k_pages, v_pages, tokens, positions,
@@ -616,10 +649,10 @@ class PagedEngine:
                                               [l_l, l_r])
                     return att[..., :r_lat].astype(q_lat.dtype), (cp2, rp2)
 
-                y, (cp2, rp2) = mla_layer(cfg, p_i, xx, positions[:, None],
-                                          attend_latent,
-                                          mlp_fn=self._mlp_fn(seg))
-                return y, cp2, rp2
+                y, (cp2, rp2), st = mla_layer(
+                    cfg, p_i, xx, positions[:, None], attend_latent,
+                    mlp_fn=self._mlp_fn(seg))
+                return y, cp2, rp2, st
         else:
             def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
                 # rk_i: (n, R, Hkv, Dh)
@@ -644,16 +677,17 @@ class PagedEngine:
                         jnp.concatenate([mask_r, mask_l], 1))
                     return att.reshape(q.shape).astype(q.dtype), (kp2, vp2)
 
-                y, (kp2, vp2) = gqa_layer(cfg, p_i, xx, positions[:, None],
-                                          attend, mlp_fn=self._mlp_fn(seg))
-                return y, kp2, vp2
+                y, (kp2, vp2), st = gqa_layer(
+                    cfg, p_i, xx, positions[:, None], attend,
+                    mlp_fn=self._mlp_fn(seg))
+                return y, kp2, vp2, st
 
-        x, k_pages, v_pages = self._run_segments(params, k_pages, v_pages,
-                                                 rk, rv, x, body)
+        x, k_pages, v_pages, routes = self._run_segments(
+            params, k_pages, v_pages, rk, rv, x, body)
         x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.vocab_size,
+        logits = unembed(output_head(params), x, cfg.vocab_size,
                          fp32=cfg.logits_fp32)[:, 0]
-        return logits, k_pages, v_pages
+        return logits, k_pages, v_pages, routes
 
     # -- ServingBackend protocol -------------------------------------------------
 
@@ -784,9 +818,11 @@ class PagedEngine:
         return (self.ecfg.seed * 1_000_003 + req.request_id * 7919
                 + 0x5BD1) & 0x7FFFFFFF
 
-    def _sample_rows(self, logits, reqs_by_row):
+    def _sample_rows(self, logits, reqs_by_row, routes=()):
         """Fused per-slot sampling. ``reqs_by_row``: list (len = batch rows)
-        of Request or None (inactive row). Returns (tokens, logprobs) np."""
+        of Request or None (inactive row). Returns (tokens, logprobs) np.
+        ``routes``: the iteration's routing counts, read in the same sync
+        (:meth:`_emit_routes`)."""
         n = logits.shape[0]
         temp = np.zeros(n, np.float32)
         topk = np.zeros(n, np.int32)
@@ -809,7 +845,28 @@ class PagedEngine:
                                     jnp.asarray(topk), jnp.asarray(topp))
         tr = self.trace
         with tr.span("engine", "sync") if tr is not None else NO_SPAN:
-            return np.asarray(toks), np.asarray(lps)
+            out = np.asarray(toks), np.asarray(lps)
+            self._emit_routes(routes)
+            return out
+
+    def _emit_routes(self, routes) -> None:
+        """Telemetry of the expert layers: for each ``(program, counts)`` of
+        ``routes`` (the MoE program calls of one iteration, in call order;
+        ``counts`` the (MoE layers, 2) device array of held assignments and
+        held experts hit) one ring event ``engine``/``moe_route`` with
+        ``program``, ``assigned`` and ``hit`` per layer, and the counters
+        ``moe_<program>_assigned`` / ``moe_<program>_hit``. Empties
+        ``routes``."""
+        if not routes:
+            return
+        for (program, _), counts in zip(
+                routes, jax.device_get([c for _, c in routes])):
+            assigned, hit = counts[:, 0].tolist(), counts[:, 1].tolist()
+            self.trace.instant("engine", "moe_route", program=program,
+                               assigned=assigned, hit=hit)
+            self.metrics.count(f"moe_{program}_assigned", sum(assigned))
+            self.metrics.count(f"moe_{program}_hit", sum(hit))
+        routes.clear()
 
     def _sample_one(self, req: Request, logits_row):
         toks, lps = self._sample_rows(logits_row[None], [req])
@@ -887,6 +944,9 @@ class PagedEngine:
 
         # --- prefill chunks (initiation phase) ---
         forked: List[Request] = []
+        # (program, routing counts) of each MoE program call, telemetry only
+        routes: list = []
+        track = self.metrics is not None and self._moe_layers > 0
         t_host = now  # when the newest sampled tokens reached the host
         ps = self.ecfg.page_size
         for ch in plan.chunks:
@@ -924,11 +984,14 @@ class PagedEngine:
             with tr.span("engine", "chunk", rid=req.request_id,
                          start=ch.start, length=ch.length, last=ch.is_last) \
                     if tr is not None else NO_SPAN:
-                logits, self.k_pages, self.v_pages = self._prefill_chunk_fn(
-                    self.params, self.k_pages, self.v_pages,
-                    jnp.asarray(tok_arr)[None], jnp.asarray(page_arr),
-                    jnp.int32(ch.start), jnp.int32(ch.length),
-                    jnp.int32(r_base), rk, rv)
+                logits, self.k_pages, self.v_pages, moe = \
+                    self._prefill_chunk_fn(
+                        self.params, self.k_pages, self.v_pages,
+                        jnp.asarray(tok_arr)[None], jnp.asarray(page_arr),
+                        jnp.int32(ch.start), jnp.int32(ch.length),
+                        jnp.int32(r_base), rk, rv)
+            if track:
+                routes.append(("prefill", moe))
             if ch.is_last:
                 tok, lp = self._sample_one(req, logits)
                 t_host = now + (time.monotonic() - t0)
@@ -962,12 +1025,13 @@ class PagedEngine:
                 # ride along with r_base = 0.
                 self._check_zero_copy_ok()
                 rk, rv = self._lease_kv_batch(row_reqs)
-                logits, self.k_pages, self.v_pages = self._decode_zc_fn(
-                    self.params, self.k_pages, self.v_pages,
-                    jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
-                    jnp.asarray(lens), jnp.asarray(rbase), rk, rv)
+                logits, self.k_pages, self.v_pages, moe = \
+                    self._decode_zc_fn(
+                        self.params, self.k_pages, self.v_pages,
+                        jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
+                        jnp.asarray(lens), jnp.asarray(rbase), rk, rv)
             else:
-                logits, self.k_pages, self.v_pages = self._decode_fn(
+                logits, self.k_pages, self.v_pages, moe = self._decode_fn(
                     self.params, self.k_pages, self.v_pages,
                     jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
                     jnp.asarray(lens))
@@ -980,12 +1044,18 @@ class PagedEngine:
                     self.metrics.count(
                         "decode_table_pages",
                         len(decode_reqs) * self.max_pages_per_seq)
-            sampled, lps = self._sample_rows(logits, row_reqs)
+            if track:
+                routes.append(("decode", moe))
+            sampled, lps = self._sample_rows(logits, row_reqs, routes)
             t_host = now + (time.monotonic() - t0)
             for req in decode_reqs:
                 slot = self.slots[req.request_id]
                 self._emit(req, slot, int(sampled[slot]), float(lps[slot]),
                            t_host)
+
+        if routes:  # an iteration of prefill chunks alone
+            with tr.span("engine", "sync"):
+                self._emit_routes(routes)
 
         finished = self.scheduler.complete_iteration(plan, t_host)
         for req in finished:

@@ -392,7 +392,7 @@ def test_suffix_prefill_logits_match_full_prefill(model_setup):
     pages[:len(table.blocks)] = table.blocks
     rk = jnp.zeros((eng.nlayers, 0, cfg.num_kv_heads, cfg.head_dim),
                    eng.k_pages.dtype)
-    suffix_logits, _, _ = eng._prefill_chunk_fn(
+    suffix_logits, _, _, _ = eng._prefill_chunk_fn(
         eng.params, eng.k_pages, eng.v_pages,
         jnp.asarray(tok)[None], jnp.asarray(pages), jnp.int32(cached),
         jnp.int32(len(suffix)), jnp.int32(0), rk, rk)
