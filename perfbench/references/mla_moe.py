@@ -48,7 +48,7 @@ import numpy as np
 from perfbench import weights
 from perfbench.references.gqa import HI, PAD, Q_BLOCK, _fp8, _linear, _rms
 
-__all__ = ["logits", "widest_gaps", "moe"]
+__all__ = ["logits", "routing", "widest_gaps", "moe"]
 
 HEAD_PAD = 512       # the program pads its head's vocabulary to this
 # the quantile of a sequence's per-token gaps that ``widest_gaps`` reports
@@ -90,30 +90,52 @@ def _rope(x, pos, inv, cs):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
-def moe(x, w, *, m: Tuple, control: bool):
-    """The held experts' part and the shared experts of one MoE layer over
-    (T, d) normed activations. ``m`` = (top_k, n_group, topk_group,
-    norm_topk_prob, routed_scaling_factor, first held expert)."""
+def _route(x, router, n_held, *, m: Tuple, control: bool):
+    """``MoEGate`` over (T, d) normed activations: (T, held experts) each
+    token's weight for each expert held here, and per token its held
+    assignments and its routing margin, the nearest tie that decides a
+    held expert: between the k-th and (k+1)-th expert where either is
+    held, or the last kept and the first dropped group where either holds
+    one, as a share of the larger score (1 where no tie decides one)."""
     k, groups, top_groups, norm, scale, first = m
     t = x.shape[0]
-    router = w["router"]
-    n_router, n_held = router.shape[1], w["gate"].shape[0]
+    n_router = router.shape[1]
     scores = jax.nn.softmax(_linear(x, router, control), axis=-1)
+    is_held = (jnp.arange(n_router) >= first) & \
+        (jnp.arange(n_router) < first + n_held)
+    margin = jnp.ones(t)
     if groups > 1:
         best = scores.reshape(t, groups, -1).max(-1)
-        _, keep = jax.lax.top_k(best, top_groups)
+        top, keep = jax.lax.top_k(best, top_groups + 1)
+        holds = is_held.reshape(groups, -1).any(-1)[keep]
+        near = (top[:, -2] - top[:, -1]) / top[:, -2]
+        margin = jnp.where(holds[:, -2] | holds[:, -1], near, margin)
         gmask = jnp.zeros((t, groups), bool).at[
-            jnp.arange(t)[:, None], keep].set(True)
+            jnp.arange(t)[:, None], keep[:, :-1]].set(True)
         scores = jnp.where(jnp.repeat(gmask, n_router // groups, -1),
                            scores, 0.0)
-    wt, idx = jax.lax.top_k(scores, k)
+    wt, idx = jax.lax.top_k(scores, k + 1)
+    near = (wt[:, -2] - wt[:, -1]) / wt[:, -2]
+    margin = jnp.minimum(margin, jnp.where(
+        is_held[idx[:, -2]] | is_held[idx[:, -1]], near, 1.0))
+    wt, idx = wt[:, :k], idx[:, :k]
     if norm:
         wt = wt / (wt.sum(-1, keepdims=True) + 1e-20)
     else:
         wt = wt * scale
-    # (T, held experts): each token's weight for each expert held here
-    held = ((idx[..., None] == first + jnp.arange(n_held)) *
-            wt[..., None]).sum(1)
+    on = idx[..., None] == first + jnp.arange(n_held)
+    held = (on * wt[..., None]).sum(1)
+    return held, on.sum((1, 2)), margin
+
+
+def moe(x, w, *, m: Tuple, control: bool, stats: bool = False):
+    """The held experts' part and the shared experts of one MoE layer over
+    (T, d) normed activations. ``m`` = (top_k, n_group, topk_group,
+    norm_topk_prob, routed_scaling_factor, first held expert). With
+    ``stats``, also each token's held assignments and routing margin
+    (:func:`_route`)."""
+    held, assigned, margin = _route(x, w["router"], w["gate"].shape[0],
+                                    m=m, control=control)
 
     def one(y, e):
         g, u, dn, we = e
@@ -126,12 +148,13 @@ def moe(x, w, *, m: Tuple, control: bool):
         y = y + _linear(jax.nn.silu(_linear(x, w["shared_gate"], control)) *
                         _linear(x, w["shared_up"], control),
                         w["shared_down"], control)
-    return y
+    return (y, (assigned, margin)) if stats else y
 
 
 @partial(jax.jit, static_argnames=("m", "control"))
 def _layer(x, w, *, m: Tuple, control: bool):
-    """One layer over (T, d) activations of one sequence."""
+    """One layer over (T, d) activations of one sequence: (x, routing
+    statistics of an MoE layer as :func:`moe` gives them, or None)."""
     (h, r, dn, dr, dv, eps, scale, inv, cs, moe_m) = m
     t = x.shape[0]
     pos = jnp.arange(t)
@@ -167,8 +190,9 @@ def _layer(x, w, *, m: Tuple, control: bool):
     if moe_m is None:
         mlp = jax.nn.silu(_linear(hn, w["gate"], control)) * \
             _linear(hn, w["up"], control)
-        return x + _linear(mlp, w["down"], control)
-    return x + moe(hn, w, m=moe_m, control=control)
+        return x + _linear(mlp, w["down"], control), None
+    y, stats = moe(hn, w, m=moe_m, control=control, stats=True)
+    return x + y, stats
 
 
 @partial(jax.jit, static_argnames=("eps", "control"))
@@ -259,14 +283,12 @@ def _statics(model: Dict, dense: bool) -> Tuple:
             moe_m)
 
 
-def logits(model: Dict, seed: int, seqs: Sequence[np.ndarray],
-           firsts: Sequence[int], *,
-           control: bool = False) -> List[np.ndarray]:
-    """Float32 logits over the vocabulary at positions ``[first, len(seq))``
-    of each token sequence (the positions whose next token was served).
-    Each sequence is padded to a multiple of ``PAD`` tokens; padding comes
-    after the real tokens, so causal attention keeps it out of every row
-    read."""
+def _forward(model: Dict, seed: int, seqs: Sequence[np.ndarray],
+             control: bool):
+    """The decoder over each sequence, padded to a multiple of ``PAD``
+    tokens after its real ones (causal attention keeps the padding out of
+    every real row): (embedding table, last activations per sequence,
+    routing statistics per MoE layer and sequence)."""
     d, vocab = model["hidden_size"], model["vocab_size"]
     key = weights.base_key(seed)
     table = _matrix(key, 0, name="embed/table", rows=vocab, cols=d)
@@ -275,11 +297,37 @@ def logits(model: Dict, seed: int, seqs: Sequence[np.ndarray],
         toks = np.zeros(-(-len(seq) // PAD) * PAD, np.int32)
         toks[:len(seq)] = seq
         xs.append(jnp.take(table, jnp.asarray(toks), axis=0))
+    stats = []
     for layer in range(model["num_hidden_layers"]):
         w = _layer_weights(model, key, layer)
         m = _statics(model, "router" not in w)
-        xs = [_layer(x, w, m=m, control=control) for x in xs]
+        out = [_layer(x, w, m=m, control=control) for x in xs]
+        xs = [x for x, _ in out]
+        if out and out[0][1] is not None:
+            stats.append([tuple(np.asarray(a)[:len(seq)] for a in st)
+                          for (_, st), seq in zip(out, seqs)])
         del w
+    return table, xs, stats
+
+
+def routing(model: Dict, seed: int, seqs: Sequence[np.ndarray], *,
+            control: bool = False) -> List[List[Tuple[np.ndarray,
+                                                      np.ndarray]]]:
+    """Per MoE layer and sequence, at each of its positions: the token's
+    (token, expert) assignments to the experts held here, and its routing
+    margin (:func:`_route`: near 0 where a rounding can flip which held
+    experts it reaches)."""
+    return _forward(model, seed, seqs, control)[2]
+
+
+def logits(model: Dict, seed: int, seqs: Sequence[np.ndarray],
+           firsts: Sequence[int], *,
+           control: bool = False) -> List[np.ndarray]:
+    """Float32 logits over the vocabulary at positions ``[first, len(seq))``
+    of each token sequence (the positions whose next token was served)."""
+    d, vocab = model["hidden_size"], model["vocab_size"]
+    key = weights.base_key(seed)
+    table, xs, _ = _forward(model, seed, seqs, control)
     if model.get("tie_word_embeddings", False):
         head = table.T
     else:
@@ -305,10 +353,14 @@ def widest_gaps(ref_rows: Sequence[np.ndarray],
     precisions, and at DeepSeek-V2's widths (weights times 16,
     unnormalised) the two routings move that position's logits by up to
     8. On a v5e the program's served tokens are the reference's best at
-    75-84% of positions; the rest lie 0.07-0.33 below it at the 90th
-    percentile of each sequence and 2.3-5.2 at the largest, while the
+    75-84% of positions; the rest lie 0.04-0.35 below it at the 90th
+    percentile of each sequence and 1.5-5.2 at the largest, while the
     float8 control lies 2.9-3.5 below at the 90th percentile (4 sequences
-    in each of 3 runs). A wrong equation, or a lower precision, moves most
+    in each of 8 runs, the control in 4). The program's large gaps sit at near ties: where
+    its gap passes 1, the reference's routing margin (:func:`routing`) is
+    0.015 at the median, against 0.094 over all positions (16 sequences
+    of 4 runs); the control's large gaps sit at ordinary margins
+    (0.06-0.10). A wrong equation, or a lower precision, moves most
     positions.
     """
     gaps = []

@@ -100,15 +100,19 @@ def _gmm(lhs, rhs, sizes):
     return jax.lax.ragged_dot(lhs, rhs, sizes)
 
 
-def grouped_experts(cfg, p, x, weights, experts):
+def grouped_experts(cfg, p, x, weights, experts, valid=None):
     """The held experts' part of the layer, dropless. x: (T, D); weights,
-    experts: (T, k) from :func:`route`. Returns ((T, D), assigned, hit):
-    the (token, expert) pairs routed to experts held here and how many
-    distinct held experts they reached."""
+    experts: (T, k) from :func:`route`; valid: (T,) bool, the rows that are
+    tokens (None: every row), the others (a chunk's padding, idle decode
+    slots) neither computed nor counted and their output zero. Returns
+    ((T, D), assigned, hit): the (token, expert) pairs routed to experts
+    held here and how many distinct held experts they reached."""
     t, k = experts.shape
     n = cfg.num_experts
     local = experts.reshape(-1) - cfg.expert_offset
     held = (local >= 0) & (local < n)
+    if valid is not None:
+        held = held & jnp.repeat(valid, k)
     # held pairs sorted by expert, the others after them
     order = jnp.argsort(jnp.where(held, local, n), stable=True)
     sizes = jnp.bincount(jnp.where(held, local, n), length=n + 1)[:n]
@@ -127,14 +131,17 @@ def grouped_experts(cfg, p, x, weights, experts):
     return y.astype(x.dtype), sizes.sum(), jnp.count_nonzero(sizes)
 
 
-def moe_layer(cfg, p, x, policy: ShardingPolicy = NO_POLICY):
+def moe_layer(cfg, p, x, policy: ShardingPolicy = NO_POLICY, valid=None):
     """x: (B, S, D) -> (y (B, S, D), (assigned, hit) int32 scalars, (experts
     (B*S, k), scores (B*S, n_router))): the held routed experts' part plus
-    the shared experts, dropless."""
+    the shared experts, dropless. ``valid``: (B, S) bool, the rows that are
+    tokens, as :func:`grouped_experts` takes them."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
     weights, experts, scores = route(cfg, p["router"]["w"], xt)
-    y, assigned, hit = grouped_experts(cfg, p, xt, weights, experts)
+    y, assigned, hit = grouped_experts(
+        cfg, p, xt, weights, experts,
+        None if valid is None else valid.reshape(b * s))
     y = y.reshape(b, s, d)
     if "shared" in p:
         y = y + mlp(p["shared"], x, policy)

@@ -275,13 +275,16 @@ class PagedEngine:
 
     # -- jitted model steps ----------------------------------------------------
 
-    def _mlp_fn(self, seg):
+    def _mlp_fn(self, seg, valid):
         """Per-segment MLP dispatch for the shared layer bodies: dense
         segments use the layer default, MoE segments the dropless expert
-        layer and its routing counts (DeepSeek-V2's plan is 1 dense + N-1
-        MoE layers)."""
+        layer and its routing counts over the rows ``valid`` (B, S) marks
+        as tokens: a chunk's padding and idle decode slots are neither
+        routed to an expert nor counted (DeepSeek-V2's plan is 1 dense +
+        N-1 MoE layers)."""
         if seg.mlp_kind == "moe":
-            return lambda pm, h: moe_mod.moe_layer(self.cfg, pm, h)[:2]
+            return lambda pm, h: moe_mod.moe_layer(self.cfg, pm, h,
+                                                   valid=valid)[:2]
         return None
 
     def _run_segments(self, params, k_pages, v_pages, rk, rv, x, body):
@@ -299,7 +302,11 @@ class PagedEngine:
         leading dense layer before MoE layers) carries the whole pools and
         updates one layer at a time: slicing each segment's layers out and
         concatenating the results would hold two more copies of the
-        pools."""
+        pools. The carry is not used for one segment: compiled for a v5e,
+        danube's decode step through it peaks within 1.5 MB of the scan's
+        but copies both whole pools into the carry first (two more
+        ``bf16[24,4097,16,8,80]`` copies, 8 GB moved per step, about 10
+        ms at 819 GB/s), and Mistral's stage the same."""
         plan, segs = self.model.plan, params["segments"]
         routes = []
 
@@ -454,7 +461,7 @@ class PagedEngine:
 
                 y, (cp2, rp2), st = mla_layer(
                     cfg, p_i, xx, positions, attend_latent,
-                    mlp_fn=self._mlp_fn(seg))
+                    mlp_fn=self._mlp_fn(seg, valid_tok[None]))
                 return y, cp2, rp2, st
         else:
             def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
@@ -493,7 +500,7 @@ class PagedEngine:
 
                 y, (kp2, vp2), st = gqa_layer(
                     cfg, p_i, xx, positions, attend,
-                    mlp_fn=self._mlp_fn(seg))
+                    mlp_fn=self._mlp_fn(seg, valid_tok[None]))
                 return y, kp2, vp2, st
 
         x, k_pages, v_pages, routes = self._run_segments(
@@ -532,6 +539,7 @@ class PagedEngine:
         # inactive slots (ctx_len == 0) write to the trash page
         page_slot = jnp.where(ctx_lens > 0, page_slot, ecfg.num_pages)
         in_page = positions % ps
+        active = (ctx_lens > 0)[:, None]  # (n, 1) rows the experts take
 
         if self.flavor == "mla":
             r_lat, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -553,7 +561,7 @@ class PagedEngine:
 
                 y, (cp2, rp2), st = mla_layer(
                     cfg, p_i, xx, positions[:, None], attend_latent,
-                    mlp_fn=self._mlp_fn(seg))
+                    mlp_fn=self._mlp_fn(seg, active))
                 return y, cp2, rp2, st
         else:
             def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
@@ -576,7 +584,7 @@ class PagedEngine:
 
                 y, (kp2, vp2), st = gqa_layer(
                     cfg, p_i, xx, positions[:, None], attend,
-                    mlp_fn=self._mlp_fn(seg))
+                    mlp_fn=self._mlp_fn(seg, active))
                 return y, kp2, vp2, st
 
         rk, rv = self._no_remote(k_pages.dtype)
@@ -617,6 +625,7 @@ class PagedEngine:
         page_slot = block_tables[jnp.arange(n), loc_pos // ps]  # (n,)
         page_slot = jnp.where(ctx_lens > 0, page_slot, ecfg.num_pages)
         in_page = loc_pos % ps
+        active = (ctx_lens > 0)[:, None]
 
         if self.flavor == "mla":
             r_lat, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -651,7 +660,7 @@ class PagedEngine:
 
                 y, (cp2, rp2), st = mla_layer(
                     cfg, p_i, xx, positions[:, None], attend_latent,
-                    mlp_fn=self._mlp_fn(seg))
+                    mlp_fn=self._mlp_fn(seg, active))
                 return y, cp2, rp2, st
         else:
             def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
@@ -679,7 +688,7 @@ class PagedEngine:
 
                 y, (kp2, vp2), st = gqa_layer(
                     cfg, p_i, xx, positions[:, None], attend,
-                    mlp_fn=self._mlp_fn(seg))
+                    mlp_fn=self._mlp_fn(seg, active))
                 return y, kp2, vp2, st
 
         x, k_pages, v_pages, routes = self._run_segments(

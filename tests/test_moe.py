@@ -85,6 +85,26 @@ def test_grouped_experts_equal_every_expert_computed_densely(case, skewed):
     assert int(hit) == len(set(local[mine].tolist()))
 
 
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_rows_that_are_no_tokens_are_neither_computed_nor_counted(case):
+    """A chunk's padding and idle decode slots (``valid`` False) add no
+    output and no assignment; the token rows are as without the mask."""
+    cfg = _cfg(**CASES[case])
+    p = _params(cfg, seed=5)
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 19, D))
+    valid = jnp.arange(19)[None, :] < jnp.array([[13], [19]])
+    whole, (a_all, _), (experts, _) = moe.moe_layer(cfg, p, x)
+    got, (assigned, hit), _ = moe.moe_layer(cfg, p, x, valid=valid)
+    np.testing.assert_allclose(got[valid], whole[valid], rtol=1e-6,
+                               atol=1e-6)
+    assert not np.asarray(got[~valid]).any()
+    local = np.asarray(experts).reshape(2, 19, -1) - cfg.expert_offset
+    mine = (local >= 0) & (local < cfg.num_experts) & \
+        np.asarray(valid)[..., None]
+    assert int(assigned) == mine.sum() < int(a_all)
+    assert int(hit) == len(set(local[mine].tolist()))
+
+
 def test_the_tpu_kernel_path_equals_the_plain_one(monkeypatch):
     """The grouped matmul kernel of JAX's megablox, in interpret mode: its
     rows past the held assignments are left undefined, and the layer must
