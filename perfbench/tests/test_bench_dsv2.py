@@ -332,3 +332,20 @@ def test_the_stage_file_translates_to_the_published_model():
     assert cfg.rope_scaling.factor == 40.0 and not cfg.tie_embeddings
     for key in conf["reduced"]:
         assert key in conf["model"]
+
+
+def test_the_stage_file_states_its_published_keys_once_at_the_top():
+    """The published keys stand at the file's top level as in the model's
+    ``config.json`` and again under ``model``, which the harness reads;
+    the two copies agree key for key, and only the ``reduced`` keys
+    differ from the published model."""
+    conf = spec.Bench().config("deepseek-v2-236b-5l")
+    model = conf["model"]
+    published_keys = set(model) - {"router_experts"}
+    assert published_keys <= set(conf)
+    assert {k: conf[k] for k in published_keys} == \
+        {k: model[k] for k in published_keys}
+    assert (conf["num_hidden_layers"], conf["n_routed_experts"],
+            model["router_experts"]) == (5, 20, 160)
+    assert sorted(conf["reduced"]) == ["n_routed_experts",
+                                       "num_hidden_layers"]

@@ -435,29 +435,44 @@ class PrefixCache:
 
     def _evict(self, n_blocks: int, spill: bool) -> int:
         freed = 0
-        progress = True
-        # one tree walk per pass, not per freed block; extra passes only when
-        # evicting a leaf exposes its parent as a new eviction candidate
-        while freed < n_blocks and progress:
-            progress = False
-            for leaf in self._lru_leaves():
+        # one tree walk per call: a pass takes its leaves oldest first, and
+        # a parent that a pass leaves childless and evictable waits for the
+        # next pass, which is made of exactly those parents (every leaf of a
+        # pass is freed or spilled, so a fresh walk would find no others)
+        leaves = self._lru_leaves()
+        while freed < n_blocks and leaves:
+            exposed: List[RadixNode] = []
+            for leaf in leaves:
                 if freed >= n_blocks:
                     break
-                if spill and self.spill_budget and self._spill(leaf):
+                if spill and self.spill_budget and \
+                        self._spill(leaf, exposed):
                     freed += 1  # device page freed, KV kept on host
-                    progress = True
                     continue
                 before = self.allocator.num_free
                 self.allocator.decref(leaf.block)
                 freed += self.allocator.num_free - before
-                del leaf.parent.children[leaf.key]
-                leaf.parent = None  # take_hot_paths skips evicted nodes
-                self.num_pages -= 1
-                self.evicted_pages += 1
-                progress = True
+                self._unlink(leaf, exposed)
+            exposed.sort(key=lambda n: n.last_access)
+            leaves = exposed
         return freed
 
-    def _spill(self, leaf: RadixNode) -> bool:
+    def _unlink(self, node: RadixNode,
+                exposed: Optional[List[RadixNode]] = None) -> None:
+        """Take ``node`` out of the tree; append its parent to ``exposed``
+        when that leaves the parent an evictable leaf."""
+        parent = node.parent
+        del parent.children[node.key]
+        node.parent = None  # take_hot_paths skips evicted nodes
+        self.num_pages -= 1
+        self.evicted_pages += 1
+        if exposed is not None and parent is not self.root and \
+                not parent.children and parent.pin_count == 0 and \
+                self.allocator.refcount_of(parent.block) == 1:
+            exposed.append(parent)
+
+    def _spill(self, leaf: RadixNode,
+               exposed: Optional[List[RadixNode]] = None) -> bool:
         """Move a cold leaf's page off-device: a neighbor instance's free
         device memory first (NVLink lane), the host tier second (PCIe).
         Falls back to False (hard eviction) when neither can take it."""
@@ -465,7 +480,7 @@ class PrefixCache:
             # budget full: the coldest spilled page dies so this (more
             # recently used) one can take its slot
             self._drop_spilled(min(self._spilled,
-                                   key=lambda n: n.last_access))
+                                   key=lambda n: n.last_access), exposed)
         if self.peer_spill_fn is not None:
             # the fn copies the payload while leaf.block is still allocated
             dst = self.peer_spill_fn(leaf.block)
@@ -489,7 +504,8 @@ class PrefixCache:
         self.spilled_pages += 1
         return True
 
-    def _drop_spilled(self, node: RadixNode) -> None:
+    def _drop_spilled(self, node: RadixNode,
+                      exposed: Optional[List[RadixNode]] = None) -> None:
         """Permanently drop a spilled node (its peer loan repaid or host
         page freed, node unlinked). Spilled nodes are always leaves —
         nothing dangles."""
@@ -499,11 +515,8 @@ class PrefixCache:
             node.peer_home = node.peer_block = -1
         else:
             self.allocator.free_host_block(node.host_block)
-        del node.parent.children[node.key]
-        node.parent = None
         self._spilled.remove(node)
-        self.num_pages -= 1
-        self.evicted_pages += 1
+        self._unlink(node, exposed)
 
     def _lru_leaves(self) -> List[RadixNode]:
         """Unpinned, exclusively-tree-owned leaves, oldest first."""

@@ -127,6 +127,36 @@ def test_evict_never_frees_referenced_page():
     assert a.num_free == 16
 
 
+def test_evict_walks_the_tree_once_per_call(monkeypatch):
+    """Evicting a chain's tail exposes its parent for the next pass; the
+    passes after the first take those parents, oldest first, without
+    walking the tree again. A pinned parent is never exposed."""
+    a = BlockAllocator(16, PS)
+    c = PrefixCache(a)
+    old = list(range(PS * 3))
+    new = list(range(100, 100 + PS * 3))
+    t1, t2 = _table_for(a, old), _table_for(a, new)
+    c.insert(old, t1.blocks)
+    c.insert(new, t2.blocks)
+    a.free_table(t1)
+    a.free_table(t2)
+    walks = []
+    lru = PrefixCache._lru_leaves
+    monkeypatch.setattr(PrefixCache, "_lru_leaves",
+                        lambda self: walks.append(1) or lru(self))
+    # pass 1 takes both tails, pass 2 the older chain's middle
+    assert c.evict(3) == 3 and len(walks) == 1
+    assert len(c.match(old)) == 1 and len(c.match(new)) == 2
+    t3 = _table_for(a, old)
+    assert c.insert(old, t3.blocks) == 2  # the first page is there
+    a.free_table(t3)
+    pinned = c.match(old[:PS])
+    c.lock(pinned)
+    # "old"'s last two pages go, its pinned first page stays; "new" goes
+    assert c.evict(10) == 4 and len(walks) == 2
+    assert c.num_pages == 1 and len(c.match(old)) == 1
+
+
 def test_hit_rate_stats():
     a = BlockAllocator(16, PS)
     c = PrefixCache(a)
