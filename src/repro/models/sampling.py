@@ -26,23 +26,52 @@ def filter_logits(logits, top_k, top_p):
     float in (0, 1], 1 = disabled. Returns logits with filtered entries at
     ``-inf``. Nucleus keeps the *smallest* set of highest-probability tokens
     whose mass reaches ``top_p`` (the argmax always survives).
+
+    One stable sort gives the descending values and their ids together.
+    Both cuts keep a prefix of that order, ``m`` positions long, and the
+    mask is built in id space from the last kept entry (value ``thr``, id
+    ``last``): a stable sort puts equal values in ascending id order, so
+    ``x > thr | (x == thr & id <= last)`` is exactly that prefix, ties
+    included. No (n, V) gather or scatter.
     """
     n, v = logits.shape
     logits = logits.astype(jnp.float32)
-    order = jnp.argsort(-logits, axis=-1)  # descending
-    # rank[i, tok] = position of tok in row i's descending order
-    ranks = jnp.zeros((n, v), jnp.int32).at[
-        jnp.arange(n)[:, None], order].set(jnp.arange(v, dtype=jnp.int32))
-    k_eff = jnp.where(top_k > 0, top_k, v)
-    keep_k = ranks < k_eff[:, None]
-    sorted_logits = jnp.take_along_axis(logits, order, axis=-1)
+    ids = jax.lax.broadcasted_iota(jnp.int32, (n, v), 1)
+    neg_sorted, order = jax.lax.sort((-logits, ids), dimension=1,
+                                     num_keys=1, is_stable=True)
+    sorted_logits = -neg_sorted  # descending; negation is exact
     probs_sorted = jax.nn.softmax(sorted_logits, axis=-1)
     # exclusive cumulative mass: token t is kept iff the mass strictly above
     # it is still short of top_p  ->  smallest set with mass >= top_p
     cum_excl = jnp.cumsum(probs_sorted, axis=-1) - probs_sorted
-    keep_sorted = cum_excl < top_p[:, None]
-    keep_p = jnp.take_along_axis(keep_sorted, ranks, axis=-1)
-    return jnp.where(keep_k & keep_p, logits, -jnp.inf)
+    keep_p = cum_excl < top_p[:, None]
+    # the first position the nucleus drops: a kept set that rounding made
+    # other than a prefix can only shrink to its leading run, never grow
+    m_p = jnp.where(keep_p.all(-1), v, jnp.argmax(~keep_p, axis=-1))
+    m = jnp.minimum(m_p, jnp.where(top_k > 0, top_k, v))
+    at = jnp.maximum(m - 1, 0)[:, None]
+    thr = jnp.take_along_axis(sorted_logits, at, axis=-1)
+    last = jnp.take_along_axis(order, at, axis=-1)
+    keep = ((logits > thr) | ((logits == thr) & (ids <= last))) \
+        & (m > 0)[:, None]
+    return jnp.where(keep, logits, -jnp.inf)
+
+
+def _greedy_rows(logits, *_):
+    return greedy(logits)
+
+
+def _draw(logits, seeds, steps, temperature, top_k, top_p):
+    """Tokens of a batch in which some row samples: greedy rows get a dummy
+    temperature of 1 so scaling stays finite, and their argmax."""
+    sampled = temperature > 0
+    safe_t = jnp.where(sampled, temperature, 1.0)[:, None]
+    scaled = filter_logits(logits / safe_t, top_k, top_p)
+    keys = jax.vmap(
+        lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(
+            seeds, steps)
+    drawn = jax.vmap(jax.random.categorical)(keys, scaled).astype(jnp.int32)
+    return jnp.where(sampled, drawn, greedy(logits))
 
 
 def sample_batch(logits, seeds, steps, temperature, top_k, top_p):
@@ -55,18 +84,16 @@ def sample_batch(logits, seeds, steps, temperature, top_k, top_p):
     temperature-shaped distribution). Returns ``(tokens (n,) int32,
     logprobs (n,) float32)`` — logprobs are log p(token) under the raw
     (unfiltered, unscaled) distribution, for best-of-n ranking.
+
+    A batch whose rows are all greedy takes the argmax alone: the filter's
+    sort and the draw run only when some row samples (one program, both
+    branches compiled).
     """
     logits = logits.astype(jnp.float32)
-    greedy_tok = greedy(logits)
-    # greedy rows get a dummy temperature of 1 so scaling stays finite;
-    # their sampled value is discarded below
-    safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
-    scaled = filter_logits(logits / safe_t, top_k, top_p)
-    keys = jax.vmap(
-        lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(
-            seeds, steps)
-    drawn = jax.vmap(jax.random.categorical)(keys, scaled).astype(jnp.int32)
-    tokens = jnp.where(temperature > 0, drawn, greedy_tok)
+    # the branches take every input as an operand (no closure), so eager
+    # calls reuse one traced cond
+    tokens = jax.lax.cond((temperature > 0).any(), _draw, _greedy_rows,
+                          logits, seeds, steps, temperature, top_k, top_p)
     logprobs = jnp.take_along_axis(
         jax.nn.log_softmax(logits, axis=-1), tokens[:, None], axis=-1)[:, 0]
     return tokens, logprobs

@@ -849,6 +849,10 @@ class PagedEngine:
             # cumulative token index: keeps the stream aligned across
             # preemption/recompute (committed tokens advance the counter)
             steps[i] = req.total_generated
+        if self.metrics is not None:
+            # calls, and calls in which some row samples: only those sort
+            self.metrics.count("sample_calls")
+            self.metrics.count("sample_sorted_calls", int((temp > 0).any()))
         toks, lps = self._sample_fn(logits, jnp.asarray(seeds),
                                     jnp.asarray(steps), jnp.asarray(temp),
                                     jnp.asarray(topk), jnp.asarray(topp))

@@ -11,6 +11,7 @@ import pytest
 from repro.configs import smoke_config
 from repro.core.scheduling.request import Request
 from repro.models import Model
+from repro.serving.api import SamplingParams
 from repro.serving.engine import EngineConfig, PagedEngine
 
 
@@ -193,6 +194,31 @@ def test_engine_counts_live_decode_pages(model_setup_f32):
     c = eng.metrics.counters
     assert c["decode_live_pages"] == 9 + 1 + 8 + 9
     assert c["decode_table_pages"] == (2 + 1 + 1) * 32
+
+
+def test_engine_counts_sampler_calls_that_sort(model_setup):
+    """``sample_calls`` counts every sampler call; ``sample_sorted_calls``
+    only those in which some row samples, the calls that take the filter's
+    sort. Greedy traffic leaves the second at zero."""
+    cfg, model, params = model_setup
+
+    def run(sampled):
+        eng = PagedEngine(cfg, params, EngineConfig(
+            num_pages=32, page_size=8, max_slots=2, enable_telemetry=True))
+        eng.add_request(Request(0, 0.0, [3, 4, 5, 6, 7], max_new_tokens=5))
+        eng.add_request(Request(1, 0.0, [8, 9, 10], max_new_tokens=2,
+                                sampling=sampled))
+        eng.run_to_completion()
+        return eng.metrics.counters
+
+    greedy = run(None)
+    assert greedy["sample_calls"] > 0
+    assert greedy["sample_sorted_calls"] == 0
+    mixed = run(SamplingParams(temperature=0.8, top_p=0.9, seed=5,
+                               max_new_tokens=2))
+    assert mixed["sample_calls"] == greedy["sample_calls"]
+    # request 1's prefill and decode sort; request 0's later decodes do not
+    assert 0 < mixed["sample_sorted_calls"] < mixed["sample_calls"]
 
 
 def test_engine_swa_arch(model_setup):

@@ -1,6 +1,9 @@
 """Vectorized per-slot sampling: top-k / top-p filter invariants, greedy
-exactness, and per-request stream determinism (batch- and slot-independent)."""
+exactness, per-request stream determinism (batch- and slot-independent),
+and equality with the earlier rank-scatter filter kept below as the
+reference."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -135,3 +138,165 @@ def test_temperature_mass_property(seed):
     p = np.array(jnp.exp(logits[0] - jnp.max(logits[0])))  # writable copy
     p /= p.sum()
     assert np.mean(p[lo]) >= np.mean(p[hi]) - 1e-3
+
+
+# -- the rank-scatter filter it replaced -----------------------------------------
+# Frozen copies of the earlier ``filter_logits`` / ``sample_batch``: an argsort,
+# a full-vocabulary rank scatter and two full-vocabulary gathers, on every row
+# whatever its temperature. The sampler must keep their sets, tokens and
+# logprobs exactly.
+
+
+def _frozen_filter_logits(logits, top_k, top_p):
+    n, v = logits.shape
+    logits = logits.astype(jnp.float32)
+    order = jnp.argsort(-logits, axis=-1)
+    ranks = jnp.zeros((n, v), jnp.int32).at[
+        jnp.arange(n)[:, None], order].set(jnp.arange(v, dtype=jnp.int32))
+    k_eff = jnp.where(top_k > 0, top_k, v)
+    keep_k = ranks < k_eff[:, None]
+    sorted_logits = jnp.take_along_axis(logits, order, axis=-1)
+    probs_sorted = jax.nn.softmax(sorted_logits, axis=-1)
+    cum_excl = jnp.cumsum(probs_sorted, axis=-1) - probs_sorted
+    keep_sorted = cum_excl < top_p[:, None]
+    keep_p = jnp.take_along_axis(keep_sorted, ranks, axis=-1)
+    return jnp.where(keep_k & keep_p, logits, -jnp.inf)
+
+
+def _frozen_sample_batch(logits, seeds, steps, temperature, top_k, top_p):
+    logits = logits.astype(jnp.float32)
+    greedy_tok = sampling.greedy(logits)
+    safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
+    scaled = _frozen_filter_logits(logits / safe_t, top_k, top_p)
+    keys = jax.vmap(
+        lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(
+            seeds, steps)
+    drawn = jax.vmap(jax.random.categorical)(keys, scaled).astype(jnp.int32)
+    tokens = jnp.where(temperature > 0, drawn, greedy_tok)
+    logprobs = jnp.take_along_axis(
+        jax.nn.log_softmax(logits, axis=-1), tokens[:, None], axis=-1)[:, 0]
+    return tokens, logprobs
+
+
+_filter = jax.jit(sampling.filter_logits)
+_frozen_filter = jax.jit(_frozen_filter_logits)
+_sample_new = jax.jit(sampling.sample_batch)
+_sample_frozen = jax.jit(_frozen_sample_batch)
+
+
+def _tied_rows(rng, n, v, bf16=True, scale=3.0):
+    """Rows rounded through bf16 (many exact ties) or left in float32."""
+    x = jnp.asarray(rng.normal(size=(n, v)).astype(np.float32) * scale)
+    return x.astype(jnp.bfloat16).astype(jnp.float32) if bf16 else x
+
+
+def _assert_prefix_of_stable_sort(logits, kept):
+    """Each row's kept set is a leading run of its stable descending sort
+    (equal values in ascending id order)."""
+    for row, k in zip(np.asarray(logits), kept):
+        order = np.argsort(-row, kind="stable")
+        in_order = k[order]
+        m = int(in_order.sum())
+        assert in_order[:m].all() and not in_order[m:].any()
+
+
+@pytest.mark.parametrize("case", [
+    "bf16_ties", "float32", "top_k_0_1", "top_p_1", "top_k_past_v",
+    "top_p_tiny", "top_p_0", "flat_rows", "neg_inf_entries"])
+def test_filter_keeps_the_frozen_set(case):
+    rng = np.random.default_rng(sum(map(ord, case)))
+    n, v = 8, 257
+    logits = _tied_rows(rng, n, v, bf16=case != "float32")
+    top_k = rng.integers(0, 40, n).astype(np.int32)
+    top_p = rng.uniform(0.05, 1.0, n).astype(np.float32)
+    if case == "top_k_0_1":
+        top_k = np.array([0, 1] * (n // 2), np.int32)
+    if case == "top_p_1":
+        top_p = np.ones(n, np.float32)
+        top_k[::2] = 0
+    if case == "top_k_past_v":
+        top_k[:] = v + 5
+    if case == "top_p_tiny":
+        top_p = np.full(n, 1e-6, np.float32)
+        top_k[:] = 0
+    if case == "top_p_0":  # keeps nothing, as the frozen filter does
+        top_p[::2] = 0.0
+    if case == "flat_rows":  # every entry of a row ties with every other
+        logits = jnp.zeros((n, v), jnp.float32).at[1::2].set(0.5)
+    if case == "neg_inf_entries":  # a masked vocabulary tail
+        logits = logits.at[:, v - 60:].set(-jnp.inf)
+        top_p[:2] = 1.0
+        top_k[:2] = 0
+    top_k, top_p = jnp.asarray(top_k), jnp.asarray(top_p)
+    want = _frozen_filter(logits, top_k, top_p)
+    got = _filter(logits, top_k, top_p)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _assert_prefix_of_stable_sort(logits, _kept(want))
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.integers(0, 2**31 - 1), st.booleans())
+def test_kept_set_is_a_stable_sort_prefix_property(seed, bf16):
+    """The frozen filter keeps a prefix of the stable descending order, so
+    the threshold mask keeps exactly its set."""
+    rng = np.random.default_rng(seed)
+    n, v = 8, 257
+    logits = _tied_rows(rng, n, v, bf16=bf16, scale=rng.uniform(0.5, 8.0))
+    top_k = jnp.asarray(rng.integers(0, v + 1, n) * rng.integers(0, 2, n),
+                        jnp.int32)
+    top_p = jnp.asarray(rng.uniform(0.01, 1.0, n).astype(np.float32))
+    want = _frozen_filter(logits, top_k, top_p)
+    _assert_prefix_of_stable_sort(logits, _kept(want))
+    np.testing.assert_array_equal(
+        np.asarray(_filter(logits, top_k, top_p)), np.asarray(want))
+
+
+def _params(kind, n, rng):
+    temp = rng.uniform(0.3, 2.0, n).astype(np.float32)
+    if kind == "greedy":
+        temp[:] = 0.0
+    elif kind == "mixed":
+        temp[::2] = 0.0
+    top_k = (rng.integers(0, 20, n) * rng.integers(0, 2, n)).astype(np.int32)
+    top_p = np.where(rng.random(n) < 0.5, 1.0,
+                     rng.uniform(0.2, 0.95, n)).astype(np.float32)
+    seeds = rng.integers(0, 2**31 - 1, n).astype(np.int32)
+    steps = rng.integers(0, 5000, n).astype(np.int32)
+    return [jnp.asarray(a) for a in (seeds, steps, temp, top_k, top_p)]
+
+
+@pytest.mark.parametrize("bf16", [True, False])
+@pytest.mark.parametrize("kind", ["greedy", "sampled", "mixed"])
+def test_sample_batch_matches_frozen(kind, bf16):
+    """Identical tokens and logprobs, bit for bit, for fixed (seed, step)
+    streams, whether the batch takes the greedy branch or the sort."""
+    rng = np.random.default_rng([len(kind), sum(map(ord, kind)), bf16])
+    n, v = 8, 257
+    for _ in range(3):
+        logits = _tied_rows(rng, n, v, bf16=bf16)
+        args = _params(kind, n, rng)
+        toks, lps = _sample_new(logits, *args)
+        want_toks, want_lps = _sample_frozen(logits, *args)
+        np.testing.assert_array_equal(np.asarray(toks), np.asarray(want_toks))
+        np.testing.assert_array_equal(np.asarray(lps), np.asarray(want_lps))
+
+
+def test_one_sampled_row_leaves_greedy_rows_alone():
+    """A batch that takes the sorted branch for one row gives its greedy
+    rows the tokens and logprobs of an all-greedy batch."""
+    rng = np.random.default_rng(17)
+    n, v = 8, 257
+    logits = _tied_rows(rng, n, v)
+    seeds, steps, _, top_k, top_p = _params("greedy", n, rng)
+    greedy_temp = jnp.zeros(n, jnp.float32)
+    one_temp = greedy_temp.at[3].set(1.3)
+    g_toks, g_lps = _sample_new(logits, seeds, steps, greedy_temp, top_k,
+                                top_p)
+    m_toks, m_lps = _sample_new(logits, seeds, steps, one_temp, top_k, top_p)
+    rest = np.arange(n) != 3
+    np.testing.assert_array_equal(np.asarray(m_toks)[rest],
+                                  np.asarray(g_toks)[rest])
+    np.testing.assert_array_equal(np.asarray(m_lps)[rest],
+                                  np.asarray(g_lps)[rest])
+    assert list(np.asarray(g_toks)) == \
+        list(np.argmax(np.asarray(logits), -1))

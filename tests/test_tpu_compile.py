@@ -8,10 +8,14 @@ shapes: 8 slots, 32 query heads over 8 KV heads, head_dim 80, 16-token
 pages, 1024 pages per sequence (its 16k context), 2049 pool pages; and at
 the benchmark's serving widths: danube with 16 slots and 4097 pool pages,
 and Mistral-Large-2407's 96 query heads over 8 KV heads at head_dim 128.
+The sampler is compiled at the benchmark's slots x vocabulary shapes too.
 
 The topology is described inside a fixture, never at import: the process
 that describes it loads the TPU library and keeps it until it exits.
 """
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.paged_attention import paged_attention
+from repro.models import sampling
 
 PAGE_SIZE = 16
 SLOTS = 8
@@ -101,3 +106,36 @@ def test_paged_attention_compiles_at_serving_width(
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def _hlo_ops(text, op):
+    """The element counts of each array the compiled module's ``op``
+    instructions produce (a tuple-shaped op gives one per member)."""
+    out = []
+    for lhs in re.findall(rf"= (\(?[^=]*?\)?) {op}\(", text):
+        out.append([math.prod(int(d) for d in dims.split(",") if d)
+                    for dims in re.findall(r"\[([\d,]*)\]", lhs)])
+    return out
+
+
+@pytest.mark.parametrize("n,v", [
+    (128, 102400),  # DeepSeek-V2's stage: 128 slots, its whole vocabulary
+    (16, 32768),    # Mistral-Large-2407: 16 slots
+])
+def test_sampler_compiles_without_full_vocabulary_index_ops(one_chip, n, v):
+    """The sampler reads no (n, V) gather and writes no (n, V) scatter: one
+    stable sort gives values and ids together, the kept set is a threshold
+    mask, and an all-greedy batch takes the conditional's argmax branch."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(sampling.sample_batch).lower(
+        spec((n, v), jnp.float32), spec((n,), jnp.int32),
+        spec((n,), jnp.int32), spec((n,), jnp.float32),
+        spec((n,), jnp.int32), spec((n,), jnp.float32)).compile().as_text()
+    for op in ("gather", "scatter"):
+        sizes = [max(s) for s in _hlo_ops(text, op)]
+        assert all(s < n * v for s in sizes), (op, sizes)
+    sorts = [s for s in _hlo_ops(text, "sort") if max(s) >= n * v]
+    assert sorts == [[n * v, n * v]]  # (values, ids), once
+    assert len(_hlo_ops(text, "conditional")) == 1
