@@ -162,7 +162,7 @@ def serve_one_chip(cfg, use_kernel: bool, seed: int, wave1, wave2):
         n = MAX_SLOTS
         zeros = np.zeros(n, np.int32)
         hlo = PagedEngine._decode_fn.lower(
-            eng, eng.params, eng.k_pages, eng.v_pages, zeros, zeros,
+            eng, eng.params, eng.pools, zeros, zeros,
             np.zeros((n, eng.max_pages_per_seq), np.int32),
             zeros).compile().as_text()
         check("tpu_custom_call" in hlo,
@@ -209,7 +209,7 @@ def four_chips(cfg, seed: int, devices) -> None:
     t0 = time.monotonic()
     replicas = [build_engine(cfg, engine_config(False), seed=seed, device=d)
                 for d in devices[:4]]
-    pool_devices = [e.k_pages.devices() | e.v_pages.devices()
+    pool_devices = [set().union(*(p.devices() for p in e.pools))
                     for e in replicas]
     print(f"replica pool devices: {pool_devices}", flush=True)
     check(all(len(d) == 1 for d in pool_devices)

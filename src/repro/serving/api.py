@@ -5,9 +5,9 @@ The paper's serving study (§III.E) compares ORCA/vLLM/InfiniteLLM as
 modeled on vLLM's ``LLM`` / ``SamplingParams`` split:
 
 * :class:`SamplingParams` — per-request decoding knobs (temperature, top-k,
-  top-p, stop tokens, best-of-n, seed). Sampling is no longer an engine-global
-  ``EngineConfig.temperature``; every request carries its own params and the
-  fused decode samples all slots with vectorized per-slot parameters.
+  top-p, stop tokens, best-of-n, seed). Every request carries its own params
+  (greedy when it brings none) and the fused decode samples all slots with
+  vectorized per-slot parameters.
 * :class:`RequestOutput` / :class:`CompletionChunk` — results. ``generate``
   returns finished outputs with finish reasons and latency metrics;
   ``stream`` yields per-iteration chunks as the engine steps.

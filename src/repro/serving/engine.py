@@ -32,8 +32,7 @@ The engine implements the :class:`~repro.serving.api.ServingBackend`
 protocol; drive it through :class:`~repro.serving.api.LLMService` rather
 than hand-rolling ``step()`` loops. Per-request sampling lives on
 ``Request.sampling`` (:class:`~repro.serving.api.SamplingParams`);
-``EngineConfig.temperature`` is **deprecated** and only seeds the default
-params for requests submitted without any. Best-of-n requests
+requests submitted without any decode greedily. Best-of-n requests
 (``SamplingParams.n > 1``) COW-fork the parent's block table right after
 its prefill — siblings share every prompt page and diverge through the
 allocator's copy-on-write on the first partial-page write, with the engine
@@ -45,11 +44,14 @@ prefill+decode tokens into one ragged batch; XLA needs static shapes, so
 prefills run as separate padded calls while decodes fuse across slots — the
 iteration-level scheduling semantics (early exit, late join) are identical.
 
-Supports every *attention-cached* arch family (GQA/MQA/SWA). For paging, the
-block tables, COW forks and preemption come straight from ``core.paging``.
-The per-layer math (ln → qkv+rope → attend → wo → mlp) is the shared
-:func:`repro.models.attention.gqa_layer` body, parameterized here by paged
-attends.
+Serves uniform GQA (MQA/SWA) or MLA stacks. For paging, the block tables,
+COW forks and preemption come straight from ``core.paging``; the page pools
+are one device array per pool of the arch's
+:class:`~repro.core.paging.layout.KVPageLayout`. The per-layer math (ln →
+qkv+rope → attend → wo → mlp) is the models' shared
+:func:`~repro.models.attention.gqa_layer` / ``mla_layer`` body, which both
+step programs run through one paged attend: write the new payloads, then
+read.
 """
 
 from __future__ import annotations
@@ -96,6 +98,22 @@ def _pow2_bucket(n: int, floor: int = 8) -> int:
     return p
 
 
+def _take_pages(pools, pages):
+    """Each pool's ``pages`` (L, n, ps, *token_shape), on the pools'
+    device."""
+    idx = jnp.asarray(pages, jnp.int32)
+    return tuple(p[:, idx] for p in pools)
+
+
+def _merge_two(q, local, remote, scale=None):
+    """DistAttention over local and borrowed keys: the masked partial of
+    each ``(k, v, mask)`` side (:func:`attention_partial`), merged by
+    log-sum-exp."""
+    parts = [attention_partial(q, k, v, mask, scale=scale)
+             for k, v, mask in (local, remote)]
+    return merge_partials_tree(*zip(*parts))
+
+
 @dataclasses.dataclass
 class EngineConfig:
     num_pages: int = 512
@@ -106,10 +124,6 @@ class EngineConfig:
     # the pools live on a TPU, the XLA gather elsewhere; True / False force
     # one (True on the CPU runs the kernel in interpret mode)
     use_kernel: Optional[bool] = None
-    # DEPRECATED: per-request SamplingParams (serving.api) supersede the
-    # engine-global temperature; this only seeds the default params applied
-    # to requests submitted without `sampling` set.
-    temperature: float = 0.0
     seed: int = 0
     # per-sequence context cap; None falls back to ArchConfig.max_seq_len and
     # then to the whole page supply. Sizes the (n, max_pages) block-table
@@ -175,19 +189,18 @@ class PagedEngine:
         if self.flavor == "mla" and cfg.sliding_window:
             raise ValueError("MLA + sliding window is unsupported")
         self.nlayers = cfg.num_layers
-        L, P, ps = cfg.num_layers, ecfg.num_pages, ecfg.page_size
-        # +1 trash page: inactive decode slots park their writes there.
-        # Pool attribute names stay ``k_pages``/``v_pages`` for every
-        # layout — they are "pool A"/"pool B" of ``kv_layout.pools`` (MLA:
-        # ckv / krope); all page-granular plumbing (COW, swap, spill,
-        # export) indexes only axis 1 and never the trailing token shape.
-        shape_a, shape_b = self.kv_layout.pool_shapes(P + 1, ps)
+        P, ps = ecfg.num_pages, ecfg.page_size
         # the pools live where the params were committed: one engine per
         # device, each replica on its own chip (one device only — the
         # unpacking refuses params spread over several)
         (self.device,) = jax.tree.leaves(params)[0].devices()
-        self.k_pages = jnp.zeros(shape_a, cfg.param_dtype, device=self.device)
-        self.v_pages = jnp.zeros(shape_b, cfg.param_dtype, device=self.device)
+        # one device array per pool of the layout (GQA: k, v; MLA: ckv,
+        # krope), +1 trash page: inactive decode slots park their writes
+        # there. Page-granular plumbing (COW, swap, spill, export) indexes
+        # only axis 1 and never the trailing token shape.
+        self.pools = tuple(
+            jnp.zeros(shape, cfg.param_dtype, device=self.device)
+            for shape in self.kv_layout.pool_shapes(P + 1, ps))
         self.use_kernel = ecfg.use_kernel if ecfg.use_kernel is not None \
             else self.flavor == "gqa" and self.device.platform == "tpu"
         self.allocator = BlockAllocator(P, ps,
@@ -210,23 +223,22 @@ class PagedEngine:
         # time — swap-out MUST copy before anything later in the same
         # schedule() can reallocate-and-write the freed device pages
         if ecfg.host_pages:
-            H = ecfg.host_pages
-            h_shape_a, h_shape_b = self.kv_layout.pool_shapes(H, ps)
-            self.h_k_pages = np.zeros(h_shape_a, self.k_pages.dtype)
-            self.h_v_pages = np.zeros(h_shape_b, self.v_pages.dtype)
+            self.host_pools = tuple(
+                np.zeros(shape, pool.dtype) for shape, pool in zip(
+                    self.kv_layout.pool_shapes(ecfg.host_pages, ps),
+                    self.pools))
             self.scheduler.swap_out_hook = self._swap_out_copy
             self.scheduler.swap_in_hook = self._swap_in_copy
-            # double-buffered (issue/complete) halves for speculative
-            # swap-outs: the allocator's pending ledger keeps the source
-            # pages allocated and immutable while "in flight"
-            self.scheduler.swap_issue_hook = self._swap_out_issue
-            self.scheduler.swap_complete_hook = self._swap_out_complete
-            self.scheduler.swap_cancel_hook = self._swap_out_cancel
+            # speculative swap-outs: the allocator's pending ledger keeps
+            # the source pages allocated and unwritten while "in flight",
+            # so the copy waits for the complete half (issue and cancel
+            # move nothing)
+            self.scheduler.swap_complete_hook = self._swap_out_copy
             if self.prefix_cache is not None:
-                self.prefix_cache.spill_out_fn = self._spill_out_copy
-                self.prefix_cache.spill_in_fn = self._spill_in_copy
+                self.prefix_cache.spill_out_fn = self._pages_to_host
+                self.prefix_cache.spill_in_fn = self._pages_from_host
         else:
-            self.h_k_pages = self.h_v_pages = None
+            self.host_pools = None
         self.swapped_out = 0
         self.swapped_in = 0
         # block-table width: the real per-sequence context limit, not the
@@ -240,15 +252,14 @@ class PagedEngine:
         self.last_token = np.zeros(ecfg.max_slots, np.int32)
         self.iterations = 0
         self.preemptions = 0
-        # requests submitted without sampling params fall back to the
-        # (deprecated) engine-global temperature, greedy by default
-        self._default_sp = SamplingParams(temperature=ecfg.temperature)
+        # requests submitted without sampling params decode greedily
+        self._default_sp = SamplingParams()
         self._sample_fn = jax.jit(sampling.sample_batch)
         # best-of-n children awaiting their parent's prefill (COW fork)
         self._pending_forks: Dict[int, List[Request]] = {}
-        # zero-copy cluster serving: reader(home_instance) -> (k_pages,
-        # v_pages) of the creditor engine's pools, wired by RouterBackend
-        # when borrowed-rBlock serving is enabled
+        # zero-copy cluster serving: reader(home_instance) -> the creditor
+        # engine's pools, wired by RouterBackend when borrowed-rBlock
+        # serving is enabled
         self.remote_reader = None
         # per-lease gathered creditor K/V (immutable while leased)
         self._lease_kv_cache: Dict[int, tuple] = {}
@@ -276,7 +287,7 @@ class PagedEngine:
     # -- jitted model steps ----------------------------------------------------
 
     def _mlp_fn(self, seg, valid):
-        """Per-segment MLP dispatch for the shared layer bodies: dense
+        """Per-segment MLP dispatch for the shared layer body: dense
         segments use the layer default, MoE segments the dropless expert
         layer and its routing counts over the rows ``valid`` (B, S) marks
         as tokens: a chunk's padding and idle decode slots are neither
@@ -287,15 +298,15 @@ class PagedEngine:
                                                    valid=valid)[:2]
         return None
 
-    def _run_segments(self, params, k_pages, v_pages, rk, rv, x, body):
+    def _run_segments(self, params, pools, remote, x, body):
         """Thread ``x`` through every segment of the plan with the layer
-        axis of both page pools (and of the remote payload arrays).
-        ``body(seg, p_i, poolA, poolB, rA_i, rB_i, x) -> (x, poolA',
-        poolB', stats)`` runs ONE layer (``stats``: the MoE layer's
-        (assigned, hit) routing counts, None for a dense MLP); stacked
-        segments (seg.n > 1) ``lax.scan`` it over their stacked params.
-        Returns (x, k_pages, v_pages, routes) with ``routes`` the (MoE
-        layers, 2) int32 counts in layer order (None without MoE layers).
+        axis of the page pools (and of the remote payloads, None without a
+        lease). ``body(seg, p_i, pools_i, remote_i, x) -> (x, pools_i',
+        stats)`` runs ONE layer (``stats``: the MoE layer's (assigned, hit)
+        routing counts, None for a dense MLP); stacked segments (seg.n > 1)
+        ``lax.scan`` it over their stacked params. Returns (x, pools,
+        routes) with ``routes`` the (MoE layers, 2) int32 counts in layer
+        order (None without MoE layers).
 
         A plan of one segment scans the pools' layers as ``xs``/``ys``,
         which XLA writes into the result in place. A plan of several (a
@@ -320,205 +331,195 @@ class PagedEngine:
 
             def scan_body(carry, scanned):
                 xx, = carry
-                p_i, kp, vp, rk_i, rv_i = scanned
-                xx, kp2, vp2, st = body(seg, p_i, kp, vp, rk_i, rv_i, xx)
-                return (xx,), (kp2, vp2, st)
+                xx, pools_i, st = body(seg, *scanned, xx)
+                return (xx,), (pools_i, st)
 
-            (x,), (k_pages, v_pages, st) = jax.lax.scan(
-                scan_body, (x,), (segs[0], k_pages, v_pages, rk, rv))
+            (x,), (pools, st) = jax.lax.scan(scan_body, (x,),
+                                             (segs[0], pools, remote))
             note(st, True)
         else:
             off = 0
             for seg, p_seg in zip(plan, segs):
                 def one(carry, scanned, seg=seg, off=off):
-                    xx, kp, vp = carry
+                    xx, pl = carry
                     i, p_i = scanned
                     layer = off + i
-                    xx, kp2, vp2, st = body(seg, p_i, kp[layer], vp[layer],
-                                            rk[layer], rv[layer], xx)
-                    kp = jax.lax.dynamic_update_index_in_dim(kp, kp2, layer,
-                                                             0)
-                    vp = jax.lax.dynamic_update_index_in_dim(vp, vp2, layer,
-                                                             0)
-                    return (xx, kp, vp), st
+                    xx, pl_i, st = body(
+                        seg, p_i, tuple(p[layer] for p in pl),
+                        jax.tree.map(lambda r: r[layer], remote), xx)
+                    pl = tuple(jax.lax.dynamic_update_index_in_dim(
+                        p, new, layer, 0) for p, new in zip(pl, pl_i))
+                    return (xx, pl), st
 
                 if seg.n == 1:
-                    (x, k_pages, v_pages), st = one(
-                        (x, k_pages, v_pages), (0, p_seg))
+                    (x, pools), st = one((x, pools), (0, p_seg))
                     note(st, False)
                 else:
-                    (x, k_pages, v_pages), st = jax.lax.scan(
-                        one, (x, k_pages, v_pages),
-                        (jnp.arange(seg.n), p_seg))
+                    (x, pools), st = jax.lax.scan(
+                        one, (x, pools), (jnp.arange(seg.n), p_seg))
                     note(st, True)
                 off += seg.n
         routes = jnp.concatenate(routes, 0) if routes else None
-        return x, k_pages, v_pages, routes
+        return x, pools, routes
 
-    def _no_remote(self, dtype):
-        """Zero-token remote payload arrays (one per pool) for calls
-        without a zero-copy lease — shape (L, 0, *token_shape)."""
-        a, b = self.kv_layout.pools
-        L = self.nlayers
-        return (jnp.zeros((L, 0) + a.token_shape, dtype),
-                jnp.zeros((L, 0) + b.token_shape, dtype))
+    def _forward(self, params, pools, remote, tokens, positions, w_page,
+                 w_off, valid, read, length=None):
+        """The model both step programs run: embed ``tokens`` (B, S), then
+        every layer writes its new per-token payloads, one per pool, and
+        attends over the written pools through ``read(seg, *queries, pools,
+        remote_i)`` (GQA queries: ``q``; MLA: ``q_lat, qr``; ``remote_i``:
+        the layer's borrowed payloads, None without a lease). ``w_page`` and
+        ``w_off`` are flat (rows,) arrays, a row per chunk token or decode
+        slot: the page and offset its payload lands at; ``valid`` marks the
+        rows that are tokens the experts take, flat or (B, S).
+        ``positions`` are the layers' RoPE positions. The final norm and
+        the unembedding close it, over each sequence's row ``length - 1``
+        (a chunk's last real token; ``length`` is traced) or over all rows.
+        Returns (logits (B, S', V), pools, routes)."""
+        cfg = self.cfg
+        layer = mla_layer if self.flavor == "mla" else gqa_layer
+        rows = w_page.shape[0]
+
+        def body(seg, p_i, pools_i, rem_i, xx):
+            def attend(*args):
+                # the queries, then one new payload per pool
+                qs, new = args[:-len(pools_i)], args[-len(pools_i):]
+                written = tuple(
+                    pool.at[w_page, w_off].set(
+                        a.reshape((rows,) + pool.shape[2:]).astype(
+                            pool.dtype))
+                    for pool, a in zip(pools_i, new))
+                return read(seg, *qs, written, rem_i), written
+
+            return layer(cfg, p_i, xx, positions, attend, mlp_fn=self._mlp_fn(
+                seg, valid.reshape(xx.shape[:2])))
+
+        x = embed(params["embed"], tokens)
+        x, pools, routes = self._run_segments(params, pools, remote, x, body)
+        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
+        if length is not None:
+            x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+        logits = unembed(output_head(params), x, cfg.vocab_size,
+                         fp32=cfg.logits_fp32)
+        return logits, pools, routes
 
     @partial(jax.jit, static_argnums=(0,))
-    def _prefill_chunk_fn(self, params, k_pages, v_pages, tokens, page_ids,
-                          start, length, r_base, rk, rv):
+    def _prefill_chunk_fn(self, params, pools, tokens, page_ids, start,
+                          length, remote=None):
         """One prefill chunk at absolute positions ``[start, start+length)``.
 
         tokens: (1, S) chunk token ids padded to a power-of-two bucket
-        (positions past ``length`` are pad: their K/V scatters to the trash
-        page and their outputs are discarded); page_ids: (n,) physical pages
-        — also pow2-padded with the trash page — covering *local* context
+        (positions past ``length`` are pad: their payloads scatter to the
+        trash page and their outputs are discarded); page_ids: (n,)
+        physical pages — also pow2-padded — covering *local* context
         positions ``[r_base, start+length)`` in order: radix-cached prefix
         pages, pages written by earlier chunks, and the pages this chunk
-        lands in. ``start`` / ``length`` / ``r_base`` are traced scalars, so
-        chunk boundaries (and token-level cache hits mid-page) recompile
+        lands in. ``start`` / ``length`` / ``r_base`` are traced scalars,
+        so chunk boundaries (and token-level cache hits mid-page) recompile
         only per shape *bucket* — a mixed-length workload compiles O(log)
         variants, not one per (chunk_len, n_pages) pair. Each chunk token's
-        K/V is scattered to its (page, offset) slot, then the chunk queries
-        attend causally over every gathered context page — positions beyond
-        each query are masked, so stale contents past the chunk's end (and
-        the pad pages, which sit at even higher positions) are never read.
+        payload is scattered to its (page, offset) slot, then the chunk
+        queries attend causally over every gathered context page —
+        positions beyond each query are masked, so stale contents past the
+        chunk's end (and the pad pages, which sit at even higher positions)
+        are never read.
 
-        Zero-copy remote prefix: ``rk``/``rv`` (L, R, *token_shape) carry
-        the borrowed pages' payloads (gathered from the creditor instance's
-        pools — K/V for GQA, ckv/krope for MLA), serving absolute positions
-        ``[0, r_base)``; the local causal partial and the remote partial are
-        combined with the DistAttention log-sum-exp merge. ``R = 0`` (the
-        common case) keeps the original single-softmax path bit-for-bit.
+        Zero-copy remote prefix: ``remote = (r_base, *payloads)``, one
+        (L, R, *token_shape) payload per pool holding the borrowed pages'
+        contents (gathered from the creditor instance's pools), serving
+        absolute positions ``[0, r_base)``; the local causal partial and
+        the remote partial are combined with the DistAttention log-sum-exp
+        merge. ``remote=None`` (the common case) is ``r_base = 0`` and
+        keeps the single-softmax path bit-for-bit.
 
-        MLA stacks scatter the *latent* per-token payload (ckv, krope) into
-        the two pools and attend with the matrix-absorbed effective
-        single-kv-head form (``mla_effective_kv``), so pages hold
-        ``kv_lora_rank + qk_rope_head_dim`` elements per token per layer
-        instead of ``2 * Hkv * Dh``.
+        GQA scatters K/V and attends with ``blockwise_attention``. MLA
+        scatters the *latent* per-token payload (ckv, krope) and attends
+        with the matrix-absorbed effective single-kv-head form
+        (``mla_effective_kv``), so pages hold ``kv_lora_rank +
+        qk_rope_head_dim`` elements per token per layer instead of ``2 *
+        Hkv * Dh``.
 
-        Returns (logits (V,) of the last real chunk position, k_pages,
-        v_pages, routes); callers ignore the logits for non-final chunks;
-        ``routes`` holds each MoE layer's routing counts
-        (:meth:`_run_segments`). Subsumes
-        whole-prompt prefill (start=0, one chunk) and cached-suffix prefill
-        (start = cached tokens).
+        Returns (logits (V,) of the last real chunk position, pools,
+        routes); callers ignore the logits for non-final chunks; ``routes``
+        holds each MoE layer's routing counts (:meth:`_run_segments`).
+        Subsumes whole-prompt prefill (start=0, one chunk) and
+        cached-suffix prefill (start = cached tokens).
         """
         cfg = self.cfg
         ecfg = self.ecfg
         ps = ecfg.page_size
         s = tokens.shape[1]
         npg = page_ids.shape[0]
-        n_remote = rk.shape[1]
+        r_base, payloads = (0, None) if remote is None \
+            else (remote[0], remote[1:])
         positions = start + jnp.arange(s)        # (s,) absolute
         valid_tok = jnp.arange(s) < length
         loc_pos = positions - r_base             # position within local pages
         # pad tokens park their writes on the trash page, like inactive
-        # decode slots — real pages never see pad K/V
+        # decode slots — real pages never see pad payloads
         tok_pages = jnp.where(
             valid_tok, page_ids[jnp.clip(loc_pos // ps, 0, npg - 1)],
             ecfg.num_pages)
         in_page = loc_pos % ps
-        x = embed(params["embed"], tokens)  # (1, s, d)
+        if remote is not None:
+            # local keys sit at absolute positions r_base + [0, npg*ps),
+            # remote keys at [0, r_base): all remote positions precede
+            # every chunk query, so only validity masks the remote side
+            key_pos = r_base + jnp.arange(npg * ps)
+            mask_l = positions[None, :, None] >= key_pos[None, None, :]
+            mask_r = (jnp.arange(payloads[0].shape[1]) < r_base)[
+                None, None, :] & jnp.ones((1, s, 1), bool)
+
+        def context(pools):  # (1, npg*ps, *token_shape) per pool
+            return [p[page_ids].reshape((1, npg * ps) + p.shape[2:])
+                    for p in pools]
 
         if self.flavor == "mla":
-            r_lat, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-            scale = _mla_scale(cfg)
+            r_lat, scale = cfg.kv_lora_rank, _mla_scale(cfg)
 
-            def body(seg, p_i, cp, rp, rc_i, rr_i, xx):
-                # cp/rp: (P+1, ps, r) / (P+1, ps, dr) latent pools;
-                # rc_i/rr_i: (R, r) / (R, dr) borrowed latent payloads
-
-                def attend_latent(q_lat, qr, ckv_new, krope_new):
-                    cp2 = cp.at[tok_pages, in_page].set(
-                        ckv_new[0].astype(cp.dtype))
-                    rp2 = rp.at[tok_pages, in_page].set(
-                        krope_new[0].astype(rp.dtype))
-                    ckv_all = cp2[page_ids].reshape(1, npg * ps, r_lat)
-                    kr_all = rp2[page_ids].reshape(1, npg * ps, dr)
-                    q_eff, k_eff, v_eff = mla_effective_kv(
-                        q_lat, qr, ckv_all.astype(q_lat.dtype),
-                        kr_all.astype(q_lat.dtype))
-                    if n_remote == 0:
-                        ctx = blockwise_attention(q_eff, k_eff, v_eff,
-                                                  causal=True, q_offset=start,
-                                                  scale=scale)
-                    else:
-                        key_pos = r_base + jnp.arange(npg * ps)
-                        mask_l = positions[None, :, None] >= \
-                            key_pos[None, None, :]
-                        o_l, m_l, l_l = attention_partial(
-                            q_eff, k_eff, v_eff, mask_l, scale=scale)
-                        kr_eff, vr_eff = mla_effective_ctx(
-                            rc_i[None].astype(q_lat.dtype),
-                            rr_i[None].astype(q_lat.dtype))
-                        mask_r = (jnp.arange(n_remote) < r_base)[None, None, :] \
-                            & jnp.ones((1, s, 1), bool)
-                        o_r, m_r, l_r = attention_partial(
-                            q_eff, kr_eff, vr_eff, mask_r, scale=scale)
-                        ctx = merge_partials_tree([o_l, o_r], [m_l, m_r],
-                                                  [l_l, l_r])
-                    return ctx[..., :r_lat].astype(q_lat.dtype), (cp2, rp2)
-
-                y, (cp2, rp2), st = mla_layer(
-                    cfg, p_i, xx, positions, attend_latent,
-                    mlp_fn=self._mlp_fn(seg, valid_tok[None]))
-                return y, cp2, rp2, st
+            def read(seg, q_lat, qr, pools, rem):
+                ckv, krope = context(pools)
+                q_eff, k_eff, v_eff = mla_effective_kv(
+                    q_lat, qr, ckv.astype(q_lat.dtype),
+                    krope.astype(q_lat.dtype))
+                if rem is None:
+                    ctx = blockwise_attention(q_eff, k_eff, v_eff,
+                                              causal=True, q_offset=start,
+                                              scale=scale)
+                else:
+                    kr_eff, vr_eff = mla_effective_ctx(
+                        *(r[None].astype(q_lat.dtype) for r in rem))
+                    ctx = _merge_two(q_eff, (k_eff, v_eff, mask_l),
+                                     (kr_eff, vr_eff, mask_r), scale)
+                return ctx[..., :r_lat].astype(q_lat.dtype)
         else:
-            def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
-                window = cfg.sliding_window if seg.attn_kind == "swa" \
-                    else None
+            def read(seg, q, pools, rem):
+                k, v = context(pools)
+                if rem is None:
+                    window = cfg.sliding_window \
+                        if seg.attn_kind == "swa" else None
+                    return blockwise_attention(q, k.astype(q.dtype),
+                                               v.astype(q.dtype),
+                                               causal=True, window=window,
+                                               q_offset=start)
+                rk, rv = rem
+                return _merge_two(q, (k, v, mask_l),
+                                  (rk[None], rv[None], mask_r)
+                                  ).astype(q.dtype)
 
-                def attend(q, k, v):
-                    kp2 = kp.at[tok_pages, in_page].set(k[0].astype(kp.dtype))
-                    vp2 = vp.at[tok_pages, in_page].set(v[0].astype(vp.dtype))
-                    kall = kp2[page_ids].reshape(
-                        1, npg * ps, cfg.num_kv_heads, cfg.head_dim)
-                    vall = vp2[page_ids].reshape(
-                        1, npg * ps, cfg.num_kv_heads, cfg.head_dim)
-                    if n_remote == 0:
-                        ctx = blockwise_attention(q, kall.astype(k.dtype),
-                                                  vall.astype(v.dtype),
-                                                  causal=True, window=window,
-                                                  q_offset=start)
-                        return ctx, (kp2, vp2)
-                    # zero-copy: local causal partial + remote partial,
-                    # merged by log-sum-exp (DistAttention). Local keys sit
-                    # at absolute positions r_base + [0, npg*ps); remote
-                    # keys at [0, r_base) — all remote positions precede
-                    # every chunk query, so only validity masks the remote
-                    # side.
-                    key_pos = r_base + jnp.arange(npg * ps)
-                    mask_l = positions[None, :, None] >= key_pos[None, None, :]
-                    o_l, m_l, l_l = attention_partial(q, kall, vall, mask_l)
-                    mask_r = (jnp.arange(n_remote) < r_base)[None, None, :] \
-                        & jnp.ones((1, s, 1), bool)
-                    o_r, m_r, l_r = attention_partial(q, rk_i[None],
-                                                      rv_i[None], mask_r)
-                    ctx = merge_partials_tree([o_l, o_r], [m_l, m_r],
-                                              [l_l, l_r])
-                    return ctx.astype(q.dtype), (kp2, vp2)
-
-                y, (kp2, vp2), st = gqa_layer(
-                    cfg, p_i, xx, positions, attend,
-                    mlp_fn=self._mlp_fn(seg, valid_tok[None]))
-                return y, kp2, vp2, st
-
-        x, k_pages, v_pages, routes = self._run_segments(
-            params, k_pages, v_pages, rk, rv, x, body)
-        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
+        logits, pools, routes = self._forward(
+            params, pools, payloads, tokens, positions, tok_pages, in_page,
+            valid_tok, read, length=length)
         # logits of the last REAL chunk position (pad rows are garbage)
-        last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-        logits = unembed(output_head(params), last, cfg.vocab_size,
-                         fp32=cfg.logits_fp32)
-        return logits[0, 0], k_pages, v_pages, routes
+        return logits[0, 0], pools, routes
 
     @partial(jax.jit, static_argnums=(0,))
-    def _decode_fn(self, params, k_pages, v_pages, tokens, positions,
-                   block_tables, ctx_lens):
+    def _decode_fn(self, params, pools, tokens, positions, block_tables,
+                   ctx_lens, remote=None):
         """Batched one-token step over slots.
 
         tokens: (n,), positions: (n,), block_tables: (n, max_pages),
-        ctx_lens: (n,) (0 = inactive slot). Returns (logits (n, V), pages,
+        ctx_lens: (n,) (0 = inactive slot). Returns (logits (n, V), pools,
         routes) as :meth:`_prefill_chunk_fn` does.
 
         GQA runs the Pallas paged-attention kernel where ``use_kernel``
@@ -527,176 +528,88 @@ class PagedEngine:
         gathers the whole block table. MLA gathers the latent pools' whole
         table and attends in the matrix-absorbed form over the latent and
         the rotary keys in place (:func:`mla_latent_attention`; the Pallas
-        kernel is GQA-shaped); :meth:`_decode_zc_fn` gathers the whole
-        table too, in either flavor: neither has a kernel path yet."""
+        kernel is GQA-shaped).
+
+        Zero-copy remote prefixes: ``remote = (r_base, *payloads)`` where
+        some slots serve their leading context from pages *borrowed* from a
+        peer instance. r_base: (n,) borrowed tokens per slot (0 = fully
+        local — such slots reduce to the plain paged path numerically);
+        payloads: one (L, n, R, *token_shape) array per pool, the borrowed
+        pages' contents gathered from each creditor's pools, covering
+        absolute positions ``[0, r_base[i])`` of slot ``i``, read from the
+        creditor's pages in place of an RDMA fetch. Both flavors gather the
+        whole local table then: GQA attends over the borrowed and the local
+        keys in one softmax, with the numerics of the model's own decode
+        (:func:`decode_attention`); MLA merges the local and remote
+        partials with the DistAttention log-sum-exp merge (InfiniteLLM's
+        micro-attention aggregation)."""
         cfg = self.cfg
         ecfg = self.ecfg
         n = tokens.shape[0]
         ps = ecfg.page_size
-
-        x = embed(params["embed"], tokens[:, None])  # (n, 1, d)
-        page_slot = block_tables[jnp.arange(n), positions // ps]  # (n,)
+        if remote is None:
+            payloads, loc_pos, loc_lens = None, positions, ctx_lens
+        else:
+            r_base, payloads = remote[0], remote[1:]
+            loc_pos = jnp.maximum(positions - r_base, 0)  # write slot, local
+            loc_lens = jnp.maximum(ctx_lens - r_base, 0)  # local context
+            mask_r = jnp.arange(payloads[0].shape[2])[None, :] < \
+                r_base[:, None]  # (n, R)
+        page_slot = block_tables[jnp.arange(n), loc_pos // ps]  # (n,)
         # inactive slots (ctx_len == 0) write to the trash page
         page_slot = jnp.where(ctx_lens > 0, page_slot, ecfg.num_pages)
-        in_page = positions % ps
-        active = (ctx_lens > 0)[:, None]  # (n, 1) rows the experts take
+        in_page = loc_pos % ps
+
+        def context(pools):  # (n, max_pages*ps, *token_shape) per pool
+            return [p[block_tables].reshape((n, -1) + p.shape[2:])
+                    for p in pools]
 
         if self.flavor == "mla":
-            r_lat, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-            scale = _mla_scale(cfg)
+            r_lat, scale = cfg.kv_lora_rank, _mla_scale(cfg)
 
-            def body(seg, p_i, cp, rp, rc_i, rr_i, xx):
-                def attend_latent(q_lat, qr, ckv_new, krope_new):
-                    cp2 = cp.at[page_slot, in_page].set(
-                        ckv_new[:, 0].astype(cp.dtype))
-                    rp2 = rp.at[page_slot, in_page].set(
-                        krope_new[:, 0].astype(rp.dtype))
-                    ckv_all = cp2[block_tables].reshape(n, -1, r_lat)
-                    kr_all = rp2[block_tables].reshape(n, -1, dr)
-                    mask = jnp.arange(ckv_all.shape[1])[None, :] < \
-                        ctx_lens[:, None]  # (n, S)
-                    ctx = mla_latent_attention(q_lat[:, 0], qr[:, 0],
-                                               ckv_all, kr_all, mask, scale)
-                    return ctx[:, None].astype(q_lat.dtype), (cp2, rp2)
-
-                y, (cp2, rp2), st = mla_layer(
-                    cfg, p_i, xx, positions[:, None], attend_latent,
-                    mlp_fn=self._mlp_fn(seg, active))
-                return y, cp2, rp2, st
+            def read(seg, q_lat, qr, pools, rem):
+                ckv, krope = context(pools)
+                mask = jnp.arange(ckv.shape[1])[None, :] < \
+                    loc_lens[:, None]  # (n, S)
+                if rem is None:
+                    ctx = mla_latent_attention(q_lat[:, 0], qr[:, 0], ckv,
+                                               krope, mask, scale)
+                    return ctx[:, None].astype(q_lat.dtype)
+                q_eff, k_eff, v_eff = mla_effective_kv(
+                    q_lat, qr, ckv.astype(q_lat.dtype),
+                    krope.astype(q_lat.dtype))
+                kr_eff, vr_eff = mla_effective_ctx(
+                    *(r.astype(q_lat.dtype) for r in rem))
+                att = _merge_two(q_eff, (k_eff, v_eff, mask[:, None]),
+                                 (kr_eff, vr_eff, mask_r[:, None]), scale)
+                return att[..., :r_lat].astype(q_lat.dtype)
         else:
-            def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
-                window = cfg.sliding_window if seg.attn_kind == "swa" \
-                    else None
-
-                def attend(q, k, v):
-                    # write each slot's new K/V into its page, then paged
-                    # attention over the block tables
-                    kp2 = kp.at[page_slot, in_page].set(
-                        k[:, 0].astype(kp.dtype))
-                    vp2 = vp.at[page_slot, in_page].set(
-                        v[:, 0].astype(vp.dtype))
+            def read(seg, q, pools, rem):
+                if rem is None:
+                    window = cfg.sliding_window \
+                        if seg.attn_kind == "swa" else None
                     att_fn = ops.paged_attention if self.use_kernel \
                         else paged_decode_attention
-                    att = att_fn(q[:, 0], kp2, vp2, block_tables, ctx_lens,
+                    att = att_fn(q[:, 0], *pools, block_tables, ctx_lens,
                                  page_size=ps, window=window)
-                    return att.reshape(n, 1, cfg.num_heads, cfg.head_dim), \
-                        (kp2, vp2)
+                    return att.reshape(n, 1, cfg.num_heads, cfg.head_dim)
+                k, v = context(pools)
+                mask_l = jnp.arange(k.shape[1])[None, :] < \
+                    loc_lens[:, None]  # (n, S_loc)
+                # the borrowed keys hold positions [0, r_base), ahead of
+                # every local key: one softmax over both, in the model's
+                # own decode numerics
+                rk, rv = rem
+                att = decode_attention(
+                    q[:, 0], jnp.concatenate([rk, k], 1),
+                    jnp.concatenate([rv, v], 1),
+                    jnp.concatenate([mask_r, mask_l], 1))
+                return att.reshape(q.shape).astype(q.dtype)
 
-                y, (kp2, vp2), st = gqa_layer(
-                    cfg, p_i, xx, positions[:, None], attend,
-                    mlp_fn=self._mlp_fn(seg, active))
-                return y, kp2, vp2, st
-
-        rk, rv = self._no_remote(k_pages.dtype)
-        x, k_pages, v_pages, routes = self._run_segments(
-            params, k_pages, v_pages, rk, rv, x, body)
-        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = unembed(output_head(params), x, cfg.vocab_size,
-                         fp32=cfg.logits_fp32)[:, 0]
-        return logits, k_pages, v_pages, routes
-
-    @partial(jax.jit, static_argnums=(0,))
-    def _decode_zc_fn(self, params, k_pages, v_pages, tokens, positions,
-                      block_tables, ctx_lens, r_base, rk, rv):
-        """Batched one-token step where some slots serve their leading
-        context from pages *borrowed* from a peer instance (zero-copy
-        prefix lease). Arguments mirror :meth:`_decode_fn` plus:
-
-        r_base: (n,) borrowed tokens per slot (0 = fully local — such slots
-        reduce to the plain paged path numerically); rk, rv:
-        (L, n, R, *token_shape) the borrowed pages' payloads gathered from
-        each creditor's pools (K/V for GQA, ckv/krope for MLA), covering
-        absolute positions ``[0, r_base[i])`` of slot ``i``, read from the
-        creditor's pages in place of an RDMA fetch. GQA attends over the
-        borrowed and the local keys in one softmax, with the numerics of
-        the model's own decode (:func:`decode_attention`); MLA merges the
-        local and remote partials with the DistAttention log-sum-exp merge
-        (InfiniteLLM's micro-attention aggregation).
-        """
-        cfg = self.cfg
-        ecfg = self.ecfg
-        n = tokens.shape[0]
-        ps = ecfg.page_size
-        n_remote = rk.shape[2]
-
-        x = embed(params["embed"], tokens[:, None])  # (n, 1, d)
-        loc_pos = jnp.maximum(positions - r_base, 0)  # write slot, local
-        loc_lens = jnp.maximum(ctx_lens - r_base, 0)  # local context length
-        page_slot = block_tables[jnp.arange(n), loc_pos // ps]  # (n,)
-        page_slot = jnp.where(ctx_lens > 0, page_slot, ecfg.num_pages)
-        in_page = loc_pos % ps
-        active = (ctx_lens > 0)[:, None]
-
-        if self.flavor == "mla":
-            r_lat, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-            scale = _mla_scale(cfg)
-
-            def body(seg, p_i, cp, rp, rc_i, rr_i, xx):
-                # rc_i: (n, R, r), rr_i: (n, R, dr)
-                def attend_latent(q_lat, qr, ckv_new, krope_new):
-                    cp2 = cp.at[page_slot, in_page].set(
-                        ckv_new[:, 0].astype(cp.dtype))
-                    rp2 = rp.at[page_slot, in_page].set(
-                        krope_new[:, 0].astype(rp.dtype))
-                    ckv_all = cp2[block_tables].reshape(n, -1, r_lat)
-                    kr_all = rp2[block_tables].reshape(n, -1, dr)
-                    q_eff, k_eff, v_eff = mla_effective_kv(
-                        q_lat, qr, ckv_all.astype(q_lat.dtype),
-                        kr_all.astype(q_lat.dtype))
-                    s_loc = k_eff.shape[1]
-                    mask_l = (jnp.arange(s_loc)[None, :] <
-                              loc_lens[:, None])[:, None, :]
-                    o_l, m_l, l_l = attention_partial(q_eff, k_eff, v_eff,
-                                                      mask_l, scale=scale)
-                    kr_eff, vr_eff = mla_effective_ctx(
-                        rc_i.astype(q_lat.dtype), rr_i.astype(q_lat.dtype))
-                    mask_r = (jnp.arange(n_remote)[None, :] <
-                              r_base[:, None])[:, None, :]
-                    o_r, m_r, l_r = attention_partial(q_eff, kr_eff, vr_eff,
-                                                      mask_r, scale=scale)
-                    att = merge_partials_tree([o_l, o_r], [m_l, m_r],
-                                              [l_l, l_r])
-                    return att[..., :r_lat].astype(q_lat.dtype), (cp2, rp2)
-
-                y, (cp2, rp2), st = mla_layer(
-                    cfg, p_i, xx, positions[:, None], attend_latent,
-                    mlp_fn=self._mlp_fn(seg, active))
-                return y, cp2, rp2, st
-        else:
-            def body(seg, p_i, kp, vp, rk_i, rv_i, xx):
-                # rk_i: (n, R, Hkv, Dh)
-                def attend(q, k, v):
-                    kp2 = kp.at[page_slot, in_page].set(
-                        k[:, 0].astype(kp.dtype))
-                    vp2 = vp.at[page_slot, in_page].set(
-                        v[:, 0].astype(vp.dtype))
-                    kall = kp2[block_tables].reshape(
-                        n, -1, cfg.num_kv_heads, cfg.head_dim)
-                    vall = vp2[block_tables].reshape(
-                        n, -1, cfg.num_kv_heads, cfg.head_dim)
-                    mask_l = jnp.arange(kall.shape[1])[None, :] < \
-                        loc_lens[:, None]  # (n, S_loc)
-                    mask_r = jnp.arange(n_remote)[None, :] < r_base[:, None]
-                    # the borrowed keys hold positions [0, r_base), ahead of
-                    # every local key: one softmax over both, in the
-                    # model's own decode numerics
-                    att = decode_attention(
-                        q[:, 0], jnp.concatenate([rk_i, kall], 1),
-                        jnp.concatenate([rv_i, vall], 1),
-                        jnp.concatenate([mask_r, mask_l], 1))
-                    return att.reshape(q.shape).astype(q.dtype), (kp2, vp2)
-
-                y, (kp2, vp2), st = gqa_layer(
-                    cfg, p_i, xx, positions[:, None], attend,
-                    mlp_fn=self._mlp_fn(seg, active))
-                return y, kp2, vp2, st
-
-        x, k_pages, v_pages, routes = self._run_segments(
-            params, k_pages, v_pages, rk, rv, x, body)
-        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = unembed(output_head(params), x, cfg.vocab_size,
-                         fp32=cfg.logits_fp32)[:, 0]
-        return logits, k_pages, v_pages, routes
+        logits, pools, routes = self._forward(
+            params, pools, payloads, tokens[:, None], positions[:, None],
+            page_slot, in_page, (ctx_lens > 0)[:, None], read)
+        return logits[:, 0], pools, routes
 
     # -- ServingBackend protocol -------------------------------------------------
 
@@ -765,15 +678,12 @@ class PagedEngine:
             check_schema(self.kv_layout.schema,
                          getattr(lease, "schema", None),
                          where="zero-copy lease read")
-            hk, hv = self.remote_reader(lease.home)
-            idx = jnp.asarray(lease.blocks, jnp.int32)
-            L = self.nlayers
-            pa, pb = self.kv_layout.pools
             # the creditor's pools may sit on another device: the gathered
             # pages move to this engine's device once per lease
-            hit = jax.device_put(
-                (hk[:, idx].reshape((L, -1) + pa.token_shape),
-                 hv[:, idx].reshape((L, -1) + pb.token_shape)), self.device)
+            hit = jax.device_put(tuple(
+                a.reshape((self.nlayers, -1) + a.shape[3:])
+                for a in _take_pages(self.remote_reader(lease.home),
+                                     lease.blocks)), self.device)
             self._lease_kv_cache[key] = hit
         return hit
 
@@ -785,35 +695,28 @@ class PagedEngine:
     def _lease_kv_chunk(self, lease):
         """(L, Rpad, *token_shape) borrowed payloads, pow2-padded (pad
         tokens are masked by ``r_base`` inside the jitted chunk fn)."""
-        k, v = self._lease_kv(lease)
         pad = _pow2_bucket(lease.num_pages, 1) * self.ecfg.page_size \
             - lease.num_tokens
-        if pad:
-            k = jnp.pad(k, ((0, 0), (0, pad)) + ((0, 0),) * (k.ndim - 2))
-            v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-        return k, v
+        return tuple(
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            if pad else a for a in self._lease_kv(lease))
 
     def _lease_kv_batch(self, row_reqs):
         """(L, n, Rpad, *token_shape) stacked borrowed payloads for a
         decode batch (zero rows for slots without a lease)."""
         leases = self.scheduler.leases
-        L = self.nlayers
-        pa, pb = self.kv_layout.pools
         rmax = max(leases[r.request_id].num_pages for r in row_reqs
                    if r is not None and r.request_id in leases)
         rpad = _pow2_bucket(rmax, 1) * self.ecfg.page_size
-        rk = jnp.zeros((L, self.ecfg.max_slots, rpad) + pa.token_shape,
-                       self.k_pages.dtype)
-        rv = jnp.zeros((L, self.ecfg.max_slots, rpad) + pb.token_shape,
-                       self.v_pages.dtype)
+        out = [jnp.zeros((self.nlayers, self.ecfg.max_slots, rpad)
+                         + p.shape[3:], p.dtype) for p in self.pools]
         for slot, req in enumerate(row_reqs):
             if req is None or req.request_id not in leases:
                 continue
             lease = leases[req.request_id]
-            k, v = self._lease_kv(lease)
-            rk = rk.at[:, slot, :lease.num_tokens].set(k)
-            rv = rv.at[:, slot, :lease.num_tokens].set(v)
-        return rk, rv
+            out = [o.at[:, slot, :lease.num_tokens].set(a)
+                   for o, a in zip(out, self._lease_kv(lease))]
+        return tuple(out)
 
     # -- per-request sampling ----------------------------------------------------
 
@@ -950,10 +853,8 @@ class PagedEngine:
         # iteration (the old block keeps its pre-iteration contents until
         # the decode/prefill writes below)
         if plan.cow:
-            old = jnp.asarray([o for o, _ in plan.cow], jnp.int32)
-            new = jnp.asarray([w for _, w in plan.cow], jnp.int32)
-            self.k_pages = self.k_pages.at[:, new].set(self.k_pages[:, old])
-            self.v_pages = self.v_pages.at[:, new].set(self.v_pages[:, old])
+            self._put_pages([w for _, w in plan.cow],
+                            _take_pages(self.pools, [o for o, _ in plan.cow]))
 
         # --- prefill chunks (initiation phase) ---
         forked: List[Request] = []
@@ -986,23 +887,20 @@ class PagedEngine:
             s_pad = _pow2_bucket(ch.length)
             tok_arr = np.zeros(s_pad, np.int32)
             tok_arr[:ch.length] = req.prompt[ch.start:ch.end]
+            remote = None
             if r_base:
                 self._check_zero_copy_ok()
-                rk, rv = self._lease_kv_chunk(
-                    self.scheduler.leases[req.request_id])
-            else:
-                rk, rv = self._no_remote(self.k_pages.dtype)
+                remote = (jnp.int32(r_base), *self._lease_kv_chunk(
+                    self.scheduler.leases[req.request_id]))
             # the span times the dispatch (the call returns before the
             # device has run the chunk)
             with tr.span("engine", "chunk", rid=req.request_id,
                          start=ch.start, length=ch.length, last=ch.is_last) \
                     if tr is not None else NO_SPAN:
-                logits, self.k_pages, self.v_pages, moe = \
-                    self._prefill_chunk_fn(
-                        self.params, self.k_pages, self.v_pages,
-                        jnp.asarray(tok_arr)[None], jnp.asarray(page_arr),
-                        jnp.int32(ch.start), jnp.int32(ch.length),
-                        jnp.int32(r_base), rk, rv)
+                logits, self.pools, moe = self._prefill_chunk_fn(
+                    self.params, self.pools, jnp.asarray(tok_arr)[None],
+                    jnp.asarray(page_arr), jnp.int32(ch.start),
+                    jnp.int32(ch.length), remote)
             if track:
                 routes.append(("prefill", moe))
             if ch.is_last:
@@ -1032,31 +930,26 @@ class PagedEngine:
                 toks[slot] = self.last_token[slot]
                 rbase[slot] = self.scheduler.remote_tokens_of(req.request_id)
                 row_reqs[slot] = req
+            remote = None
             if rbase.any():
                 # >=1 slot reads a borrowed prefix: local paged partial +
                 # remote partial, merged (DistAttention). Fully-local slots
                 # ride along with r_base = 0.
                 self._check_zero_copy_ok()
-                rk, rv = self._lease_kv_batch(row_reqs)
-                logits, self.k_pages, self.v_pages, moe = \
-                    self._decode_zc_fn(
-                        self.params, self.k_pages, self.v_pages,
-                        jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
-                        jnp.asarray(lens), jnp.asarray(rbase), rk, rv)
-            else:
-                logits, self.k_pages, self.v_pages, moe = self._decode_fn(
-                    self.params, self.k_pages, self.v_pages,
-                    jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bt),
-                    jnp.asarray(lens))
-                if self.metrics is not None:
-                    # cumulative: the pages inside each active slot's
-                    # context and window (what the kernel reads) against
-                    # the block table's width (what the XLA gather reads)
-                    _, live = live_pages(lens, ps, self._window)
-                    self.metrics.count("decode_live_pages", int(live.sum()))
-                    self.metrics.count(
-                        "decode_table_pages",
-                        len(decode_reqs) * self.max_pages_per_seq)
+                remote = (jnp.asarray(rbase),
+                          *self._lease_kv_batch(row_reqs))
+            logits, self.pools, moe = self._decode_fn(
+                self.params, self.pools, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(bt), jnp.asarray(lens), remote)
+            if remote is None and self.metrics is not None:
+                # cumulative: the pages inside each active slot's context
+                # and window (what the kernel reads) against the block
+                # table's width (what the XLA gather reads)
+                _, live = live_pages(lens, ps, self._window)
+                self.metrics.count("decode_live_pages", int(live.sum()))
+                self.metrics.count(
+                    "decode_table_pages",
+                    len(decode_reqs) * self.max_pages_per_seq)
             if track:
                 routes.append(("decode", moe))
             sampled, lps = self._sample_rows(logits, row_reqs, routes)
@@ -1136,101 +1029,71 @@ class PagedEngine:
 
     # -- host swap tier -----------------------------------------------------------
 
-    def _swap_out_copy(self, pairs) -> None:
-        """Device -> host page payloads for one table's swap-out (scheduler
-        hook, called before the freed device pages can be reallocated)."""
-        devs = jnp.asarray([d for d, _ in pairs], jnp.int32)
+    def _pages_to_host(self, pairs) -> None:
+        """Device -> host page payloads for ``(device, host)`` page pairs:
+        the prefix cache's spill-out, and a table's swap-out (called
+        before the freed device pages can be reallocated)."""
         hosts = [h for _, h in pairs]
         tr = self.trace
         with tr.span("engine", "sync") if tr is not None else NO_SPAN:
-            self.h_k_pages[:, hosts] = np.asarray(self.k_pages[:, devs])
-            self.h_v_pages[:, hosts] = np.asarray(self.v_pages[:, devs])
+            for h, a in zip(self.host_pools,
+                            _take_pages(self.pools, [d for d, _ in pairs])):
+                h[:, hosts] = np.asarray(a)
+
+    def _pages_from_host(self, pairs) -> None:
+        """Host -> device payloads onto freshly allocated blocks for
+        ``(host, device)`` page pairs: spill-in and swap-in."""
+        hosts = [h for h, _ in pairs]
+        self._put_pages([d for _, d in pairs],
+                        [h[:, hosts] for h in self.host_pools])
+
+    def _put_pages(self, pages, payloads) -> None:
+        """Write ``payloads`` — one (L, n, ps, *token_shape) array per pool,
+        from the host, a peer or these pools — onto device ``pages``.
+        Batched: one update per pool whatever the page count, since
+        ``.at[].set`` outside jit copies the whole pool."""
+        idx = jnp.asarray(pages, jnp.int32)
+        payloads = jax.device_put(tuple(payloads), self.device)
+        self.pools = tuple(p.at[:, idx].set(a)
+                           for p, a in zip(self.pools, payloads))
+
+    def _swap_out_copy(self, pairs) -> None:
+        self._pages_to_host(pairs)
         self.swapped_out += 1
 
-    def _swap_out_issue(self, pairs) -> None:
-        """Issue half of a double-buffered swap-out: the DMA is in flight
-        against the next iteration's compute. The source device pages stay
-        allocated through the allocator's pending ledger and are never
-        written while pending, so the payload copy is deferred to the
-        complete half — byte-identical to copying now, with nothing
-        serialized into this iteration."""
-
-    def _swap_out_complete(self, pairs) -> None:
-        """Complete half: materialize the device->host payloads (sources
-        untouched since issue), called by the scheduler *before* the
-        allocator decrefs the device pages."""
-        self._swap_out_copy(pairs)
-
-    def _swap_out_cancel(self, pairs) -> None:
-        """Pressure receded before the transfer resolved: the pages never
-        left the device, nothing to copy (host blocks are returned by the
-        allocator's cancel path)."""
-
     def _swap_in_copy(self, pairs) -> None:
-        """Host -> device onto the freshly allocated blocks (batched: one
-        pool update per direction, same idiom as the COW copy in step)."""
-        hosts = [h for h, _ in pairs]
-        devs = jnp.asarray([d for _, d in pairs], jnp.int32)
-        self.k_pages = self.k_pages.at[:, devs].set(
-            jnp.asarray(self.h_k_pages[:, hosts]))
-        self.v_pages = self.v_pages.at[:, devs].set(
-            jnp.asarray(self.h_v_pages[:, hosts]))
+        self._pages_from_host(pairs)
         self.swapped_in += 1
-
-    def _spill_out_copy(self, pairs) -> None:
-        """Prefix-cache spill movers: same transfers as a table swap, kept
-        out of the swapped_out/in event counters."""
-        devs = jnp.asarray([d for d, _ in pairs], jnp.int32)
-        hosts = [h for _, h in pairs]
-        tr = self.trace
-        with tr.span("engine", "sync") if tr is not None else NO_SPAN:
-            self.h_k_pages[:, hosts] = np.asarray(self.k_pages[:, devs])
-            self.h_v_pages[:, hosts] = np.asarray(self.v_pages[:, devs])
-
-    def _spill_in_copy(self, pairs) -> None:
-        hosts = [h for h, _ in pairs]
-        devs = jnp.asarray([d for _, d in pairs], jnp.int32)
-        self.k_pages = self.k_pages.at[:, devs].set(
-            jnp.asarray(self.h_k_pages[:, hosts]))
-        self.v_pages = self.v_pages.at[:, devs].set(
-            jnp.asarray(self.h_v_pages[:, hosts]))
 
     # -- cross-instance prefix sharing -------------------------------------------
 
     def export_page_payload(self, block: int):
-        """KV contents of one physical page as host arrays, tagged with the
-        engine's :attr:`KVPageLayout.schema` — the payload a cluster router
-        publishes to the distkv board so a peer engine (same arch + params)
-        can adopt the page without recomputing it. An importer with a
-        different layout refuses the payload loudly."""
+        """KV contents of one physical page as host arrays (one per pool),
+        tagged with the engine's :attr:`KVPageLayout.schema` — the payload
+        a cluster router publishes to the distkv board so a peer engine
+        (same arch + params) can adopt the page without recomputing it. An
+        importer with a different layout refuses the payload loudly."""
         return (self.kv_layout.schema,
-                np.asarray(self.k_pages[:, block]),
-                np.asarray(self.v_pages[:, block]))
+                *(np.asarray(a)[:, 0]
+                  for a in _take_pages(self.pools, [block])))
 
     def import_page_payloads(self, blocks, payloads) -> None:
         """Materialize published pages into freshly adopted local blocks
         (counterpart of :meth:`export_page_payload`). Every payload's
         schema tag is validated against the local layout before any pool is
         touched — reinterpreting foreign-layout bytes would corrupt pages
-        silently. Batched: one update per KV pool regardless of page count
-        — ``.at[].set`` outside jit copies the whole pool, so per-page
-        calls would copy it 2x per page (same batching the COW path in
-        :meth:`step` uses)."""
+        silently. Batched: one update per pool regardless of page count
+        (:meth:`_put_pages`)."""
         if not blocks:
             return
         for p in payloads:
             check_schema(self.kv_layout.schema, p[0],
                          where="page-payload import")
-        idx = jnp.asarray(list(blocks), jnp.int32)
         # host payloads (exported by a peer on any device) go straight to
-        # this engine's device
-        k, v = jax.device_put(
-            (np.stack([p[1] for p in payloads], axis=1).astype(
-                self.k_pages.dtype),  # (L, n, ps, *token_shape)
-             np.stack([p[2] for p in payloads], axis=1).astype(
-                 self.v_pages.dtype)), self.device)
-        self.k_pages = self.k_pages.at[:, idx].set(k)
-        self.v_pages = self.v_pages.at[:, idx].set(v)
+        # this engine's device: (L, n, ps, *token_shape) per pool
+        self._put_pages(list(blocks), [
+            np.stack([p[1 + j] for p in payloads], axis=1).astype(pool.dtype)
+            for j, pool in enumerate(self.pools)])
 
     # -- disaggregated prefill/decode handoff -------------------------------------
 

@@ -99,6 +99,13 @@ def _free_pages_of(child) -> int:
     return child.allocator.num_free
 
 
+def _holds_kv(child) -> bool:
+    """Whether a child holds device KV pools (an engine) rather than
+    bookkeeping alone (a cost-model sim): page payloads move only between
+    children that hold them."""
+    return hasattr(child, "pools")
+
+
 class RoundRobinPolicy:
     """Cycle through instances in submission order."""
 
@@ -337,7 +344,7 @@ class RouterBackend:
                 raise ValueError(
                     f"handoff_mode must be one of {HANDOFF_MODES}, "
                     f"got {handoff_mode!r}")
-            kinds = {hasattr(c, "k_pages") for c in self.children}
+            kinds = {_holds_kv(c) for c in self.children}
             if len(kinds) > 1:
                 raise ValueError(
                     "disaggregated roles need homogeneous children (all "
@@ -391,7 +398,7 @@ class RouterBackend:
         for rm in self.rms.values():
             rm.register_peers(self.rms)
         for child in self.children:
-            if hasattr(child, "k_pages"):  # engine: needs creditor pools
+            if _holds_kv(child):  # engine: needs creditor pools
                 child.remote_reader = self._read_pools
 
     def _wire_zero_copy(self) -> None:
@@ -413,8 +420,7 @@ class RouterBackend:
             child.scheduler.remote_adopter = self._make_remote_adopter(i)
 
     def _read_pools(self, home: int):
-        c = self.children[home]
-        return c.k_pages, c.v_pages
+        return self.children[home].pools
 
     def _wire_peer_spill(self) -> None:
         """Attach the radix peer-spill hooks on every child: spill-out
@@ -452,14 +458,14 @@ class RouterBackend:
 
     def _make_peer_spiller(self, i: int):
         child = self.children[i]
-        child_is_engine = hasattr(child, "k_pages")
+        child_is_engine = _holds_kv(child)
 
         def spill(dev_block: int):
             # neighbor with the most free device pages (same backend kind:
             # a payload cannot move between a cost-model sim and an engine)
             best, best_free = None, -1
             for j, peer in enumerate(self.children):
-                if j == i or hasattr(peer, "k_pages") != child_is_engine:
+                if j == i or _holds_kv(peer) != child_is_engine:
                     continue
                 free = peer.allocator.num_free
                 if free > self.g.safety_free and free > best_free:
@@ -602,7 +608,7 @@ class RouterBackend:
         mode the NetworkModel decides borrow-vs-copy per request; declining
         here lets the copy importer run instead."""
         child = self.children[i]
-        child_is_engine = hasattr(child, "k_pages")
+        child_is_engine = _holds_kv(child)
 
         def adopter(req: Request, local_tokens: int):
             pc = child.prefix_cache
@@ -619,8 +625,7 @@ class RouterBackend:
                 usable.append(page)
             if home is None or home == i:
                 return None  # nothing lendable / it lives here already
-            if child_is_engine and \
-                    not hasattr(self.children[home], "k_pages"):
+            if child_is_engine and not _holds_kv(self.children[home]):
                 return None  # a sim home has no KV an engine could read
             if len(usable) * pc.page_size <= local_tokens:
                 return None  # the local tree already matches at least as far

@@ -453,11 +453,9 @@ def _gathered_prompt_kv(eng, rid, plen):
     npg = -(-plen // eng.ecfg.page_size)
     idx = jnp.asarray(table.blocks[:npg], jnp.int32)
     L = eng.cfg.num_layers
-    k = np.asarray(eng.k_pages[:, idx]).reshape(L, -1, eng.cfg.num_kv_heads,
-                                                eng.cfg.head_dim)[:, :plen]
-    v = np.asarray(eng.v_pages[:, idx]).reshape(L, -1, eng.cfg.num_kv_heads,
-                                                eng.cfg.head_dim)[:, :plen]
-    return k, v
+    return tuple(np.asarray(p[:, idx]).reshape(
+        L, -1, eng.cfg.num_kv_heads, eng.cfg.head_dim)[:, :plen]
+        for p in eng.pools)
 
 
 def test_engine_chunked_equals_monolithic(model_setup_f32):

@@ -264,3 +264,35 @@ def test_engine_kv_utilization_reported(model_setup):
     eng.step()
     util = eng.kv_utilization()
     assert 0.5 <= util <= 1.0  # 9 tokens in 2 pages of 8 = 0.5625
+
+
+@pytest.mark.parametrize("lease", [False, True])
+@pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "deepseek-v2-236b"])
+def test_step_programs_keep_their_names(arch, lease):
+    """Both step programs lower in either attention flavor, with and
+    without a zero-copy lease, as the modules ``jit__prefill_chunk_fn``
+    and ``jit__decode_fn``: the benchmark finds the programs in a device
+    trace by these names."""
+    cfg = dataclasses.replace(smoke_config(arch), sliding_window=None)
+    params = Model(cfg, remat=False).init(jax.random.PRNGKey(0))
+    eng = PagedEngine(cfg, params, EngineConfig(num_pages=16, page_size=8,
+                                                max_slots=4))
+    n, r = eng.ecfg.max_slots, 8  # slots, borrowed tokens
+    i32 = np.zeros(n, np.int32)
+    remote_c = remote_d = None
+    if lease:
+        remote_c = (jnp.int32(r), *(jnp.zeros((cfg.num_layers, r)
+                                              + p.shape[3:], p.dtype)
+                                    for p in eng.pools))
+        remote_d = (jnp.full(n, r, jnp.int32),
+                    *(jnp.zeros((cfg.num_layers, n, r) + p.shape[3:],
+                                p.dtype) for p in eng.pools))
+    chunk = PagedEngine._prefill_chunk_fn.lower(
+        eng, eng.params, eng.pools, jnp.zeros((1, 16), jnp.int32),
+        jnp.zeros(2, jnp.int32), jnp.int32(0), jnp.int32(16), remote_c)
+    decode = PagedEngine._decode_fn.lower(
+        eng, eng.params, eng.pools, i32, i32,
+        np.zeros((n, eng.max_pages_per_seq), np.int32), i32, remote_d)
+    for low, name in ((chunk, "jit__prefill_chunk_fn"),
+                      (decode, "jit__decode_fn")):
+        assert low.as_text().startswith(f"module @{name} ")

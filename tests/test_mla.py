@@ -54,9 +54,8 @@ def test_layout_mla_schema_and_pools():
     assert lay.schema == "mla:ckv64+krope16:bf16"
     # one shared latent per token, NOT per kv head: ckv + krope elems
     assert lay.bytes_per_token_layer == (64 + 16) * 2
-    (ca, cb) = lay.pool_shapes(6, PS)
-    assert ca == (lay.num_layers, 6, PS, 64)   # ckv pool
-    assert cb == (lay.num_layers, 6, PS, 16)   # krope pool
+    assert lay.pool_shapes(6, PS) == ((lay.num_layers, 6, PS, 64),  # ckv
+                                      (lay.num_layers, 6, PS, 16))  # krope
 
 
 def test_full_deepseek_latent_compression_ratio():
@@ -309,15 +308,25 @@ def _engine(cfg, params, **kw):
     return PagedEngine(cfg, params, EngineConfig(**kw))
 
 
-def test_mla_engine_pools_follow_layout(mla_setup):
+@pytest.mark.parametrize("flavor,schema", [
+    ("gqa", "gqa:k4x64+v4x64:f32"),   # k, v pools (L, P+1, ps, Hkv, Dh)
+    ("mla", "mla:ckv64+krope16:f32"),  # ckv (L, P+1, ps, r), krope (.., dr)
+])
+def test_mla_engine_pools_follow_layout(mla_setup, flavor, schema):
     cfg, model, params = mla_setup
-    eng = _engine(cfg, params)
+    if flavor == "gqa":
+        cfg = dataclasses.replace(smoke_config("h2o-danube-1.8b"),
+                                  sliding_window=None, dtype="float32")
+        params = Model(cfg, remat=False).init(jax.random.PRNGKey(0))
+    eng = _engine(cfg, params, host_pages=4)
     lay = eng.kv_layout
-    assert lay.flavor == "mla"
-    assert lay.schema == "mla:ckv64+krope16:f32"
+    assert lay.flavor == flavor
+    assert lay.schema == schema
     shapes = lay.pool_shapes(48 + 1, PS)  # +1: the trash page
-    assert eng.k_pages.shape == shapes[0]  # ckv pool (L, P+1, ps, r)
-    assert eng.v_pages.shape == shapes[1]  # krope pool (L, P+1, ps, dr)
+    assert len(eng.pools) == len(lay.pools) == len(shapes)
+    assert tuple(p.shape for p in eng.pools) == shapes
+    assert tuple(h.shape for h in eng.host_pools) == \
+        lay.pool_shapes(4, PS)
     assert eng.allocator.page_bytes == lay.page_bytes(PS)
 
 
@@ -429,12 +438,11 @@ def test_mla_engine_payload_export_import_round_trip(mla_setup):
     assert payload[0] == eng.kv_layout.schema
     assert payload[1].shape == (eng.nlayers, PS) \
         + eng.kv_layout.pools[0].token_shape
+    assert len(payload) == 1 + len(eng.pools)
     blk = eng.allocator.alloc_block()
     eng.import_page_payloads([blk], [payload])
-    np.testing.assert_array_equal(np.asarray(eng.k_pages[:, blk]),
-                                  payload[1])
-    np.testing.assert_array_equal(np.asarray(eng.v_pages[:, blk]),
-                                  payload[2])
+    for pool, page in zip(eng.pools, payload[1:]):
+        np.testing.assert_array_equal(np.asarray(pool[:, blk]), page)
     eng.allocator.decref(blk)
     foreign = (GQA_SMOKE.schema, payload[1], payload[2])
     with pytest.raises(ValueError, match="payload import"):
@@ -447,7 +455,7 @@ def test_mla_engine_handoff_install_refuses_foreign_lease(mla_setup):
     a slot is claimed."""
     cfg, model, params = mla_setup
     eng = _engine(cfg, params)
-    eng.remote_reader = lambda home: (eng.k_pages, eng.v_pages)
+    eng.remote_reader = lambda home: eng.pools
     lease = RemoteLease(home=1, debtor=0, blocks=[0], page_size=PS,
                         schema=GQA_SMOKE.schema)
     req = Request(0, 0.0, [1, 2, 3], max_new_tokens=1)

@@ -412,7 +412,7 @@ def test_suffix_prefill_logits_match_full_prefill(model_setup):
     cached = r2.num_cached_tokens
     # build the pow2-bucketed chunk inputs the engine's step() would:
     # tokens pad with zeros (masked via `length`), page_ids pad with a real
-    # page (causally masked), empty remote K/V (no zero-copy lease here)
+    # page (causally masked), no remote (no zero-copy lease here)
     from repro.serving.engine import _pow2_bucket
     suffix = prompt[cached:]
     tok = np.zeros(_pow2_bucket(len(suffix)), np.int32)
@@ -420,12 +420,9 @@ def test_suffix_prefill_logits_match_full_prefill(model_setup):
     pages = np.full(_pow2_bucket(len(table.blocks), 1), table.blocks[0],
                     np.int32)
     pages[:len(table.blocks)] = table.blocks
-    rk = jnp.zeros((eng.nlayers, 0, cfg.num_kv_heads, cfg.head_dim),
-                   eng.k_pages.dtype)
-    suffix_logits, _, _, _ = eng._prefill_chunk_fn(
-        eng.params, eng.k_pages, eng.v_pages,
-        jnp.asarray(tok)[None], jnp.asarray(pages), jnp.int32(cached),
-        jnp.int32(len(suffix)), jnp.int32(0), rk, rk)
+    suffix_logits, _, _ = eng._prefill_chunk_fn(
+        eng.params, eng.pools, jnp.asarray(tok)[None], jnp.asarray(pages),
+        jnp.int32(cached), jnp.int32(len(suffix)))
     np.testing.assert_allclose(np.asarray(suffix_logits),
                                np.asarray(full_logits), rtol=2e-4, atol=2e-4)
 

@@ -179,7 +179,7 @@ def test_replicas_share_prefix_across_devices(share_mode):
             num_pages=64, page_size=8, max_slots=4,
             enable_prefix_cache=True), device=d) for d in devs]
         for e, d in zip(engines, devs):
-            assert e.k_pages.devices() == e.v_pages.devices() == {{d}}
+            assert all(p.devices() == {{d}} for p in e.pools)
 
         class Scripted:
             def __init__(self):
